@@ -1,9 +1,10 @@
 """Jit'd model-layout wrappers around the Pallas kernels.
 
-``use_pallas(cfg)`` decides per backend: TPU -> compiled kernels; CPU (this
-container, and the dry-run's 512 host devices) -> the pure-JAX chunked paths
-in repro.models, which implement the same algorithms (the kernels are
-validated against them in interpret mode by tests/test_kernels_*.py).
+Nothing on the model path calls these yet: ``repro.models`` always takes
+its pure-JAX chunked attention and scan, which implement the same
+algorithms. The kernels are validated against those in interpret mode by
+tests/test_kernels.py, and compiled for a described TPU v5e chip by
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -15,10 +16,6 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd_scan import ssd_scan_kernel
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_kv_heads", "causal",

@@ -2,7 +2,8 @@
 
 Layout (heads pre-expanded from B/C groups by the wrapper):
   x  (BH, NC, Q, P)   head streams, chunked
-  dt (BH, NC, Q)      softplus'd step sizes
+  dt (BH, NC, 1, Q)   softplus'd step sizes (a unit sublane dim, so the
+                      block's last two dims (1, Q) equal the array's)
   B  (BH, NC, Q, N)   input projections
   C  (BH, NC, Q, N)   output projections
   A  (BH,)            per-head negative decay rate
@@ -28,6 +29,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _dot(a, b, ca: int, cb: int):
+    """f32 matmul contracting a's dim ``ca`` with b's dim ``cb``."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *, Q: int):
     i = pl.program_id(0)
 
@@ -36,33 +44,36 @@ def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *, Q: int):
         s_ref[...] = jnp.zeros_like(s_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)           # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)         # (Q,)
+    dt = dt_ref[0, 0].astype(jnp.float32)         # (1, Q)
     B = b_ref[0, 0].astype(jnp.float32)           # (Q, N)
     C = c_ref[0, 0].astype(jnp.float32)           # (Q, N)
     A = a_ref[i]                                  # scalar (negative)
 
-    dA = dt * A                                   # (Q,)
-    cum = jnp.cumsum(dA)                          # (Q,)
-    # intra-chunk: y[q] += sum_{j<=q} exp(cum_q - cum_j)·dt_j·(C_q·B_j)·x_j
-    scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # (Q,Q)
-    L = cum[:, None] - cum[None, :]
     rows = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(rows >= cols, L, NEG_INF)
-    wgt = jnp.exp(L) * scores * dt[None, :]
-    y = jax.lax.dot_general(wgt, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    causal = rows >= cols
+    dA = dt * A                                   # (1, Q)
+    # Mosaic has no cumsum: the prefix sums are matmuls against the
+    # causal mask, once as a row and once as a column
+    tril = jnp.where(causal, 1.0, 0.0)
+    cum = _dot(dA, tril, 1, 1)                    # (1, Q)
+    cum_col = _dot(tril, dA, 1, 1)                # (Q, 1)
+    # intra-chunk: y[q] += sum_{j<=q} exp(cum_q - cum_j)·dt_j·(C_q·B_j)·x_j
+    scores = _dot(C, B, 1, 1)                     # (Q, Q)
+    L = jnp.where(causal, cum_col - cum, NEG_INF)
+    wgt = jnp.exp(L) * scores * dt
+    y = _dot(wgt, x, 1, 0)
     # inter-chunk: y[q] += exp(cum_q) · C_q · S_in
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
-        C, s_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    y = y + jnp.exp(cum_col) * _dot(C, s_ref[...], 1, 0)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update: S_out = exp(cum_last)·S_in + Σ_j exp(cum_last-cum_j)·dt_j·B_j⊗x_j
-    decay_end = jnp.exp(cum[-1] - cum) * dt       # (Q,)
-    s_ref[...] = s_ref[...] * jnp.exp(cum[-1]) + jax.lax.dot_general(
-        B * decay_end[:, None], x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    cum_last = cum[:, Q - 1:]                     # (1, 1)
+    decay_end = jnp.exp(cum_last - cum) * dt      # (1, Q)
+    B_w = _dot(jnp.where(rows == cols, decay_end, 0.0), B, 1, 0)
+    # the chunk's total decay as an (N, 1) column: Mosaic cannot broadcast
+    # a (1, 1) across both sublanes and lanes
+    total = _dot(jnp.ones((s_ref.shape[0], Q), jnp.float32), dA, 1, 1)
+    s_ref[...] = s_ref[...] * jnp.exp(total) + _dot(B_w, x, 0, 0)
 
 
 def ssd_scan_kernel(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
@@ -75,7 +86,7 @@ def ssd_scan_kernel(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     assert S % Q == 0, (S, Q)
     NC = S // Q
     xs = x.reshape(BH, NC, Q, P)
-    dts = dt.reshape(BH, NC, Q)
+    dts = dt.reshape(BH, NC, 1, Q)
     Bs = B.reshape(BH, NC, Q, N)
     Cs = C.reshape(BH, NC, Q, N)
     kernel = functools.partial(_kernel, Q=Q)
@@ -85,7 +96,7 @@ def ssd_scan_kernel(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # A
             pl.BlockSpec((1, 1, Q, P), lambda i, c: (i, c, 0, 0)),  # x
-            pl.BlockSpec((1, 1, Q), lambda i, c: (i, c, 0)),        # dt
+            pl.BlockSpec((1, 1, 1, Q), lambda i, c: (i, c, 0, 0)),  # dt
             pl.BlockSpec((1, 1, Q, N), lambda i, c: (i, c, 0, 0)),  # B
             pl.BlockSpec((1, 1, Q, N), lambda i, c: (i, c, 0, 0)),  # C
         ],

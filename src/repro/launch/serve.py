@@ -30,6 +30,12 @@ with mirrored journals; ``--kill-runtime K`` runs the failure drill:
       --queue --requests 64 --runtimes 3 --kill-runtime 1 \\
       --tenants "gold:weight=10,free:weight=1:quota=8"
 
+The JSON report on stdout is also ``main``'s return value. A run whose
+single runtime lost a device group, or that did not drain, exits nonzero
+after printing it. Run as a program, the launcher keeps JAX's persistent
+compilation cache in ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and
+in the checkout's ``.jax_cache`` otherwise.
+
 ``--tenants-file spec.json`` loads the same specs from a JSON file
 (``[{"name": ..., "weight": ..., "max_inflight": ..., "slo_delay_s": ...,
 "energy_budget_j": ...}, ...]``); ``--power group=active_w:idle_w,...``
@@ -45,6 +51,7 @@ from repro.configs.base import reduced
 from repro.configs.registry import get_config
 from repro.core.energy import EnergyModel, PowerSpec
 from repro.core.types import TIERS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import parse_groups
 from repro.policy import AdaptivePolicy
 from repro.queue import Job
@@ -64,7 +71,7 @@ def parse_power(text: str) -> EnergyModel:
     return EnergyModel(specs)
 
 
-def main():
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -171,7 +178,7 @@ def main():
     ap.add_argument("--chaos-horizon-s", type=float, default=2.0,
                     help="horizon seconds for a --chaos-seed generated "
                          "plan")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.runtimes < 1:
         ap.error("--runtimes must be >= 1")
     if args.runtimes > 1 and not args.queue:
@@ -246,7 +253,7 @@ def main():
                             adaptive_refill=args.adaptive_refill)
     exporter.start()
     try:
-        _run(args, ap, eng, groups, registry, energy_model)
+        out = _run(args, eng, groups, registry, energy_model)
     finally:
         snap = exporter.stop()
         if args.metrics_out or args.trace_out or args.prom_out:
@@ -257,9 +264,17 @@ def main():
                     "self_overhead_s":
                         round(snap["self"]["est_overhead_s"], 6),
                 }}, indent=2))
+    failures = []
+    if out.get("dead_groups"):
+        failures.append(f"device group(s) died: {out['dead_groups']}")
+    if not out.get("drained", True):
+        failures.append("the queue did not drain")
+    if failures:
+        raise SystemExit("serve failed: " + "; ".join(failures))
+    return out
 
 
-def _run(args, ap, eng, groups, registry, energy_model):
+def _run(args, eng, groups, registry, energy_model) -> dict:
     if args.queue:
         # cover --requests exactly: full jobs plus a remainder job
         full, rem = divmod(args.requests, args.job_items)
@@ -297,6 +312,7 @@ def _run(args, ap, eng, groups, registry, energy_model):
                     frep.new_tokens / max(fed.time_s, 1e-9), 1),
                 "per_runtime": fed.per_runtime,
                 "per_tenant_items": fed.per_tenant_items,
+                "outputs": frep.outputs,
             }
             if frep.per_tenant:
                 out["per_tenant"] = {
@@ -304,7 +320,7 @@ def _run(args, ap, eng, groups, registry, energy_model):
                         for k, v in u.items()}
                     for t, u in frep.per_tenant.items()}
             print(json.dumps(out, indent=2))
-            return
+            return out
         policy = None
         if args.policy_window > 0:
             policy = AdaptivePolicy(window_s=args.policy_window,
@@ -329,9 +345,11 @@ def _run(args, ap, eng, groups, registry, energy_model):
                               for k, v in rep.queue_delay.items()},
             "per_group": rep.per_group_items,
             "dead_groups": rep.dead_groups,
+            "drained": rep.drained,
             "deadline_misses": rep.deadline_misses,
             "express_batches": rep.express_batches,
             "cancelled_batches": rep.cancelled_batches,
+            "outputs": rep.outputs,
         }
         if rep.per_tenant:
             out["per_tenant"] = {
@@ -345,18 +363,22 @@ def _run(args, ap, eng, groups, registry, energy_model):
         if rep.admission_per_tenant:
             out["admission_per_tenant"] = rep.admission_per_tenant
         print(json.dumps(out, indent=2))
-        return
+        return out
     rep = eng.serve(args.requests)
-    print(json.dumps({
+    out = {
         "requests": rep.requests,
         "new_tokens": rep.new_tokens,
         "time_s": round(rep.time_s, 3),
         "tok_per_s": round(rep.new_tokens / max(rep.time_s, 1e-9), 1),
         "per_group": rep.per_group_items,
+        "dead_groups": rep.dead_groups,
         "accel_overheads": {k: round(v, 4) for k, v in
                             rep.overheads.get(groups[0].name, {}).items()},
-    }, indent=2))
+    }
+    print(json.dumps(out, indent=2))
+    return out
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

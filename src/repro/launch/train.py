@@ -21,6 +21,7 @@ from repro.configs.base import reduced
 from repro.configs.registry import get_config
 from repro.core.types import DeviceKind
 from repro.core.energy import EnergyModel, PowerSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import GroupDef, HeteroTrainer
 
@@ -114,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

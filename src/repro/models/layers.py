@@ -1,6 +1,7 @@
 """Shared layers: param-spec system, norms, activations, RoPE, MLP."""
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -22,6 +23,13 @@ def is_param_def(x) -> bool:
     return isinstance(x, ParamDef)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _scaled_normal(key: jax.Array, shape, std: float, dtype):
+    # one program per leaf: run eagerly as three ops, the float32 draw and
+    # its scaled copy of every leaf could sit in device memory at once
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def init_from_defs(defs, key: jax.Array):
     """Materialize a pytree of ParamDef into concrete arrays."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_param_def)
@@ -36,8 +44,7 @@ def init_from_defs(defs, key: jax.Array):
         else:
             fan_in = d.shape[0] if d.shape else 1
             std = d.scale / math.sqrt(max(fan_in, 1))
-            out.append((jax.random.normal(k, d.shape, jnp.float32) * std)
-                       .astype(dt))
+            out.append(_scaled_normal(k, d.shape, std, dt))
     return jax.tree.unflatten(treedef, out)
 
 

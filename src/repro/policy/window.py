@@ -13,6 +13,7 @@ observation on the monitor thread), so the window stays lock-free.
 """
 from __future__ import annotations
 
+import math
 from collections import deque
 from typing import Deque, Optional, Tuple
 
@@ -62,7 +63,10 @@ class SlidingWindow:
 
     def mean(self, now: Optional[float] = None) -> float:
         vs = self.values(now)
-        return sum(vs) / len(vs) if vs else 0.0
+        if not vs:
+            return 0.0
+        # a rounded sum / n can land an ulp outside [min, max]
+        return min(max(math.fsum(vs) / len(vs), min(vs)), max(vs))
 
     def min(self, now: Optional[float] = None) -> float:
         vs = self.values(now)

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 import tempfile
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -33,6 +34,39 @@ from repro.train.trainer import GroupDef, bucket
 
 
 @dataclass
+class GroupOutputs:
+    """What one group's chunks produced in one serve call: the devices
+    their output arrays lived on, the range of the generated token ids,
+    and the first chunk's request rows with its generated tokens (the
+    whole padded batch), so a caller can recompute that chunk outside
+    the scheduler."""
+    chunks: int = 0
+    devices: set = field(default_factory=set)
+    token_min: int = 0
+    token_max: int = 0
+    rows: List[int] = field(default_factory=list)
+    tokens: Optional[np.ndarray] = None
+
+    def add(self, rows: np.ndarray, tokens: np.ndarray, devices) -> None:
+        if self.chunks == 0:
+            self.rows = [int(r) for r in rows if r >= 0]
+            self.tokens = tokens
+            self.token_min, self.token_max = int(tokens.min()), \
+                int(tokens.max())
+        else:
+            self.token_min = min(self.token_min, int(tokens.min()))
+            self.token_max = max(self.token_max, int(tokens.max()))
+        self.chunks += 1
+        self.devices.update(f"{d.platform}:{d.id}" for d in devices)
+
+    def report(self) -> Dict:
+        return {"chunks": self.chunks, "devices": sorted(self.devices),
+                "token_range": [self.token_min, self.token_max],
+                "sample": {"rows": self.rows,
+                           "tokens": self.tokens.tolist()}}
+
+
+@dataclass
 class ServeReport:
     requests: int
     new_tokens: int
@@ -40,6 +74,7 @@ class ServeReport:
     per_group_items: Dict[str, int]
     overheads: Dict[str, Dict[str, float]]
     throughput: Dict[str, float]
+    dead_groups: List[str] = field(default_factory=list)
 
 
 @dataclass
@@ -68,6 +103,9 @@ class QueueServeReport:
     deadline_misses: Dict[str, int] = field(default_factory=dict)
     express_batches: int = 0
     cancelled_batches: int = 0
+    # per-group GroupOutputs.report(): output devices, token range, and
+    # one sampled chunk
+    outputs: Dict[str, Dict] = field(default_factory=dict)
 
 
 @dataclass
@@ -78,6 +116,7 @@ class FederatedServeReport:
     drained: bool
     per_tenant: Dict[str, Dict] = field(default_factory=dict)
     new_tokens: int = 0
+    outputs: Dict[str, Dict] = field(default_factory=dict)
 
 
 class HeteroServeEngine:
@@ -104,7 +143,14 @@ class HeteroServeEngine:
         # metrics and spans land in a single registry/tracer
         self.telemetry = telemetry_mod.resolve(telemetry)
         self.params = M.init_params(cfg, jax.random.PRNGKey(seed))
+        # one copy of the params per bound device (federated runtimes on
+        # a multi-device host); the default device uses self.params
+        self._device_params: Dict[object, object] = {}
+        self._device_params_lock = threading.Lock()
         self._fns: Dict[int, tuple] = {}
+        # per executor key: what its chunks produced in the current serve
+        # call (reset at the start of each; one writer per key)
+        self._outputs: Dict[str, GroupOutputs] = {}
         # fail-injection counters persist across executors so an injected
         # group death stays dead over a queued multi-batch run
         self._fail_counters: Dict[str, Dict[str, int]] = {}
@@ -137,8 +183,23 @@ class HeteroServeEngine:
         return rng.integers(0, self.cfg.vocab, self.prompt_len,
                             dtype=np.int32)
 
-    def _make_executor(self, g: GroupDef, key: Optional[str] = None):
+    def _params_on(self, device):
+        if device is None or \
+                jax.tree.leaves(self.params)[0].devices() == {device}:
+            return self.params
+        with self._device_params_lock:
+            p = self._device_params.get(device)
+            if p is None:
+                p = self._device_params[device] = \
+                    jax.device_put(self.params, device)
+            return p
+
+    def _make_executor(self, g: GroupDef, key: Optional[str] = None,
+                       device=None):
         cfg = self.cfg
+        key = key or g.name
+        device = g.device if g.device is not None else device
+        params = self._params_on(device)
 
         def make_inputs(token):
             c = token.chunk
@@ -148,7 +209,11 @@ class HeteroServeEngine:
                 toks = np.concatenate(
                     [toks, np.zeros((pad - c.size, self.prompt_len),
                                     np.int32)])
-            out = {"tokens": toks}
+            # request row of each batch row (-1: padding), carried through
+            # the step so a chunk's output says which requests it answers
+            rows = np.full(pad, -1, np.int32)
+            rows[:c.size] = np.arange(c.begin, c.end, dtype=np.int32)
+            out = {"tokens": toks, "rows": rows}
             if cfg.prefix_len:
                 rngp = np.random.Generator(np.random.PCG64(c.begin))
                 out["prefix_emb"] = rngp.standard_normal(
@@ -156,7 +221,7 @@ class HeteroServeEngine:
                     * 0.02
             return out
 
-        counter = self._fail_counters.setdefault(key or g.name, {"n": 0})
+        counter = self._fail_counters.setdefault(key, {"n": 0})
 
         def step(batch):
             if g.fail_after_chunks is not None:
@@ -168,33 +233,37 @@ class HeteroServeEngine:
             prefill_fn, decode_fn = self._fns_for(b)
             if g.slowdown > 1.0:
                 time.sleep((g.slowdown - 1.0) * 0.001 * b)
-            logits, cache = prefill_fn(self.params, batch["tokens"],
+            logits, cache = prefill_fn(params, batch["tokens"],
                                        batch.get("prefix_emb"))
             tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
             toks = [tok]
             for _ in range(self.decode_tokens - 1):
-                logits, cache = decode_fn(self.params, cache, tok)
+                logits, cache = decode_fn(params, cache, tok)
                 tok = jnp.argmax(logits[:, -1], -1)[:, None] \
                     .astype(jnp.int32)
                 toks.append(tok)
-            return jnp.concatenate(toks, axis=1)
+            return jnp.concatenate(toks, axis=1), batch["rows"]
 
         def fetch(outs):
-            return {"tokens_out": np.asarray(outs)}
+            gen, rows = outs
+            tokens = np.asarray(gen)
+            self._outputs.setdefault(key, GroupOutputs()).add(
+                np.asarray(rows), tokens, gen.devices())
+            return {"tokens_out": tokens}
 
-        return JaxChunkExecutor(step, make_inputs, fetch, device=g.device,
+        return JaxChunkExecutor(step, make_inputs, fetch, device=device,
                                 async_depth=g.async_depth,
                                 priority_boost=g.priority_boost)
 
-    def _executor_for(self, g: GroupDef,
-                      namespace: str = "") -> JaxChunkExecutor:
+    def _executor_for(self, g: GroupDef, namespace: str = "",
+                      device=None) -> JaxChunkExecutor:
         # executors (and fail-injection counters) are cached per
         # *namespaced* name: federated runtimes must not share one
         # executor's async pipeline across their dispatcher threads
         key = namespace + g.name
         ex = self._executors.get(key)
         if ex is None:
-            ex = self._executors[key] = self._make_executor(g, key)
+            ex = self._executors[key] = self._make_executor(g, key, device)
         return ex
 
     # ------------------------------------------------------------------
@@ -202,13 +271,15 @@ class HeteroServeEngine:
                          exclude: Optional[set] = None,
                          namespace: str = "",
                          telemetry=None,
-                         wrap_executor: Optional[Callable] = None) \
-            -> DynamicScheduler:
+                         wrap_executor: Optional[Callable] = None,
+                         device=None) -> DynamicScheduler:
         """``namespace`` prefixes every group name (federation: runtime
         ``r1``'s accel group is ``r1/accel``), so per-runtime schedulers
         get private executors, distinct trace tracks, and unambiguous
         dead-group exclusion. ``wrap_executor(name, ex)`` decorates each
-        group's executor (the chaos plane's injection point)."""
+        group's executor (the chaos plane's injection point). ``device``
+        binds groups that name no device of their own (and a copy of the
+        params) to that device."""
         specs, execs = {}, {}
         for g in self.groups:
             name = namespace + g.name
@@ -218,7 +289,7 @@ class HeteroServeEngine:
                                     fixed_chunk=g.fixed_chunk,
                                     min_chunk=1, max_chunk=max_chunk,
                                     init_throughput=1.0)
-            ex = self._executor_for(g, namespace)
+            ex = self._executor_for(g, namespace, device)
             if wrap_executor is not None:
                 ex = wrap_executor(name, ex)
             execs[name] = ex
@@ -243,7 +314,11 @@ class HeteroServeEngine:
             return None
         return self.telemetry.snapshot()
 
+    def _outputs_report(self) -> Dict[str, Dict]:
+        return {k: o.report() for k, o in sorted(self._outputs.items())}
+
     def serve(self, n_requests: int) -> ServeReport:
+        self._outputs = {}
         sched = self._build_scheduler(max_chunk=n_requests)
         res = sched.run(0, n_requests)
         return ServeReport(
@@ -252,7 +327,8 @@ class HeteroServeEngine:
             time_s=res.total_time,
             per_group_items=res.per_group_items,
             overheads=res.overheads,
-            throughput=res.throughput)
+            throughput=res.throughput,
+            dead_groups=sorted(res.failed_groups))
 
     # ------------------------------------------------------------------
     # queued-submission path: requests arrive as prioritized Jobs, pass
@@ -304,6 +380,7 @@ class HeteroServeEngine:
         drains — the idle-efficiency probe scripts/smoke.sh uses to
         assert the event-driven drain isn't busy-polling.
         """
+        self._outputs = {}
         tracker = ThroughputTracker(self.alpha)
         ledger = OverheadLedger()
         ledger.keep_records = False           # bounded memory for long runs
@@ -379,7 +456,8 @@ class HeteroServeEngine:
             if admission is not None else {},
             deadline_misses=dict(st.deadline_misses),
             express_batches=st.express_batches,
-            cancelled_batches=st.cancelled_batches)
+            cancelled_batches=st.cancelled_batches,
+            outputs=self._outputs_report())
 
     # ------------------------------------------------------------------
     # federated path: N runtimes behind one front-end (repro.federation)
@@ -418,12 +496,20 @@ class HeteroServeEngine:
         string or a path to one). Executor faults wrap every group's
         executor; journal/federation faults are executed by the
         federation tier.
+
+        On a host with several devices, runtime ``rK``'s executors and a
+        copy of the params are bound to ``jax.devices()[K % n]`` (one
+        replica per chip); with one device everything stays on it.
         """
         from repro.chaos import ChaosExecutor, ChaosInjector, FaultPlan
         from repro.federation import FederatedService
         if journal_dir is None:
             journal_dir = tempfile.mkdtemp(prefix="repro-fed-")
+        self._outputs = {}
         rids = [f"r{i}" for i in range(max(1, runtimes))]
+        devices = jax.devices()
+        bound = {rid: devices[i % len(devices)] if len(devices) > 1 else None
+                 for i, rid in enumerate(rids)}
 
         chaos = None
         if chaos_plan is not None or chaos_seed is not None:
@@ -454,7 +540,8 @@ class HeteroServeEngine:
                 sched = self._build_scheduler(exclude=dead,
                                               namespace=f"{rid}/",
                                               telemetry=telemetry,
-                                              wrap_executor=wrap)
+                                              wrap_executor=wrap,
+                                              device=bound[rid])
                 sched.tracker = tracker
                 sched.ledger = ledger
                 return sched
@@ -526,4 +613,5 @@ class HeteroServeEngine:
         return FederatedServeReport(
             fed=rep, drained=drained, per_tenant=per_tenant,
             new_tokens=sum(rep.per_tenant_items.values())
-            * self.decode_tokens)
+            * self.decode_tokens,
+            outputs=self._outputs_report())

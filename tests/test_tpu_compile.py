@@ -1,0 +1,116 @@
+"""Compile the main path and the Pallas kernels for one described TPU v5e
+chip, at real widths, without a chip.
+
+The TPU compiler refuses here what interpret mode never sees: block shapes
+off the (8, 128) tiling, primitives Mosaic cannot lower, programs that do
+not fit the chip's 16 GB. Nothing runs, so these tests say nothing about
+results or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.registry import get_config
+from repro.kernels.ops import attention_bshd, decode_attention_bshd, ssd_bshn
+from repro.models import model as M
+from repro.train.trainer import bucket
+
+V5E_HBM_BYTES = 16 * 10**9
+# the serve shapes chip_smoke.py drives: stablelm-1.6b, 128-token prompts,
+# 32 decoded tokens, chunks padded to power-of-two batches of up to
+# 8 jobs x 2 requests
+PROMPT_LEN, DECODE_TOKENS = 128, 32
+BATCHES = (1, 2, 4, 8, 16)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, used
+    return used
+
+
+@pytest.mark.parametrize("batch", BATCHES)
+def test_stablelm_serve_step_compiles_for_v5e(one_chip, batch):
+    cfg = get_config("stablelm-1.6b")
+    max_len = bucket(PROMPT_LEN + DECODE_TOKENS)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    prompts = _on(one_chip,
+                  jax.ShapeDtypeStruct((batch, PROMPT_LEN), jnp.int32))
+
+    def prefill(p, t):
+        return M.prefill(cfg, p, t, None, max_len=max_len)
+
+    def decode(p, c, t):
+        return M.decode_step(cfg, p, c, t)
+
+    pre = jax.jit(prefill).lower(params, prompts).compile()
+    assert _fits(pre) > 3 * 10**9          # 1.6B bf16 params are resident
+    cache = _on(one_chip, jax.eval_shape(prefill, params, prompts)[1])
+    tok = _on(one_chip, jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    _fits(jax.jit(decode).lower(params, cache, tok).compile())
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    b, s, h, d = 8, PROMPT_LEN, 32, 64
+    x = _on(one_chip, jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16))
+    compiled = attention_bshd.lower(x, x, x, n_heads=h,
+                                    n_kv_heads=h).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_decode_compiles_for_v5e(one_chip):
+    b, h, d = 8, 32, 64
+    S = bucket(PROMPT_LEN + DECODE_TOKENS)
+    q = _on(one_chip, jax.ShapeDtypeStruct((b, 1, h, d), jnp.bfloat16))
+    kv = _on(one_chip, jax.ShapeDtypeStruct((b, S, h, d), jnp.bfloat16))
+    kv_len = _on(one_chip, jax.ShapeDtypeStruct((b,), jnp.int32))
+    compiled = decode_attention_bshd.lower(q, kv, kv, kv_len, n_heads=h,
+                                           n_kv_heads=h).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_for_v5e(one_chip):
+    ssm = get_config("zamba2-1.2b").ssm
+    b, s = 1, 4 * ssm.chunk_size
+    nh = ssm.expand * get_config("zamba2-1.2b").d_model // ssm.head_dim
+    f32 = jnp.float32
+    x = _on(one_chip, jax.ShapeDtypeStruct((b, s, nh, ssm.head_dim), f32))
+    dt = _on(one_chip, jax.ShapeDtypeStruct((b, s, nh), f32))
+    A = _on(one_chip, jax.ShapeDtypeStruct((nh,), f32))
+    BC = _on(one_chip, jax.ShapeDtypeStruct(
+        (b, s, ssm.n_groups, ssm.d_state), f32))
+    compiled = ssd_bshn.lower(x, dt, A, BC, BC,
+                              chunk=ssm.chunk_size).compile()
+    assert "tpu_custom_call" in compiled.as_text()
