@@ -1,0 +1,6 @@
+"""``memory_stats()["peak_bytes_in_use"]`` after the window, highest over
+the cell's chips, in GB (1e9 bytes)."""
+
+
+def read(run):
+    return run.memory_peak_bytes / 1e9 if run.memory_peak_bytes else None
