@@ -13,6 +13,18 @@ reproduces the paper's baseline Dynamic (synchronous clFinish()).
 
 `priority_boost` is the literal paper optimization: raise the host/dispatch
 thread's OS priority (best-effort `os.nice`; needs privileges to raise).
+
+`JaxChunkExecutor` opens a telemetry scope (ring span + profiler annotation,
+on the chunk's group track, ids group/seq) around `exec.inputs`
+(make_inputs), and profiler annotations alone around `exec.h2d`
+(device_put), `exec.wait` (the readiness wait) and `exec.fetch` (the
+device-to-host fetch), whose intervals the chunk's record already puts in
+the ring as its h2d/kernel/d2h phases; the step closure adds its own.
+Two histograms split the dispatcher thread's CPU seconds per chunk
+(`time.thread_time`): `exec.issue_cpu_s{group}` inside the step call, and
+`exec.wait_cpu_s{group}` inside the wait for its outputs. Neither counts
+the time the thread sleeps, so a wait that moves from the step call into
+the readiness poll stays counted, and a wait that spins shows as CPU.
 """
 from __future__ import annotations
 
@@ -23,6 +35,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+from repro import telemetry as telemetry_mod
 from repro.core.types import Chunk, ChunkRecord, Token
 
 clock = time.monotonic
@@ -121,7 +134,7 @@ class JaxChunkExecutor(ChunkExecutor):
                  fetch: Optional[Callable[[Any], Any]] = None,
                  device=None, async_depth: int = 1,
                  priority_boost: bool = False,
-                 completion_mode: str = "poll"):
+                 completion_mode: str = "poll", telemetry=None):
         import jax
         if completion_mode not in ("poll", "block"):
             raise ValueError(f"completion_mode must be 'poll' or 'block', "
@@ -144,6 +157,23 @@ class JaxChunkExecutor(ChunkExecutor):
         # drain would block on every unfinished chunk (worse than the
         # depth-gated baseline), so poll mode degrades to block instead.
         self._poll_ok: Optional[bool] = None
+        self.telemetry = telemetry_mod.resolve(telemetry)
+        self._cpu_hists: Dict[Tuple[str, str], Any] = {}
+
+    def _scope(self, name: str, token: Token):
+        return telemetry_mod.scope(self.telemetry, name, token.group,
+                                   group=token.group, seq=token.chunk.seq)
+
+    def _annotate(self, name: str, token: Token):
+        return telemetry_mod.annotate(self.telemetry, name,
+                                      group=token.group, seq=token.chunk.seq)
+
+    def _observe_cpu(self, name: str, group: str, seconds: float) -> None:
+        h = self._cpu_hists.get((name, group))
+        if h is None:
+            h = self._cpu_hists[name, group] = \
+                self.telemetry.registry.histogram(name, group=group)
+        h.observe(seconds)
 
     def on_worker_start(self) -> None:
         if self.priority_boost:
@@ -183,14 +213,21 @@ class JaxChunkExecutor(ChunkExecutor):
     def _complete_oldest(self, known_ready: bool = False) -> ChunkRecord:
         rec, outs = self._inflight.popleft()
         try:
+            if self.telemetry is not None:
+                cpu = time.thread_time()
             if known_ready:     # readiness just probed by the caller:
                 # skip the poll loop, keep the no-op barrier for leaves
                 # without a probe
                 self.jax.block_until_ready(outs)
             else:
-                self._wait_ready(outs)
+                with self._annotate("exec.wait", rec.token):
+                    self._wait_ready(outs)
+            if self.telemetry is not None:
+                self._observe_cpu("exec.wait_cpu_s", rec.token.group,
+                                  time.thread_time() - cpu)
             rec.tg4 = clock()
-            res = self.fetch(outs)
+            with self._annotate("exec.fetch", rec.token):
+                res = self.fetch(outs)
             rec.tg5 = clock()
         except BaseException:
             # the popped chunk is in neither _inflight nor the caller's
@@ -217,15 +254,22 @@ class JaxChunkExecutor(ChunkExecutor):
                     done.append(self._complete_oldest(known_ready=True))
             while len(self._inflight) >= self.async_depth:
                 done.append(self._complete_oldest())
-            host_inputs = self.make_inputs(token)
-            rec.tg1 = clock()
-            dev_inputs = self.jax.device_put(host_inputs, self.device) \
-                if self.device is not None \
-                else self.jax.device_put(host_inputs)
+            with self._scope("exec.inputs", token):
+                host_inputs = self.make_inputs(token)
+            with self._annotate("exec.h2d", token):
+                rec.tg1 = clock()
+                dev_inputs = self.jax.device_put(host_inputs, self.device) \
+                    if self.device is not None \
+                    else self.jax.device_put(host_inputs)
+            if self.telemetry is not None:
+                cpu = time.thread_time()
             rec.tg2 = clock()
             outs = self.step(*dev_inputs) if isinstance(dev_inputs, tuple) \
                 else self.step(dev_inputs)
             rec.tg3 = clock()                   # dispatch returned (async)
+            if self.telemetry is not None:
+                self._observe_cpu("exec.issue_cpu_s", token.group,
+                                  time.thread_time() - cpu)
             if self._poll_ok is None:
                 self._poll_ok = any(
                     hasattr(leaf, "is_ready")

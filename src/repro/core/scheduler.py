@@ -34,6 +34,13 @@ excluded in later epochs; remaining groups absorb the work (work
 conservation is property-tested). Elasticity: add_group() mid-run spawns a
 new dispatcher thread that joins the oldest open epoch; remove_group()
 drains a group out everywhere.
+
+Each dispatcher opens telemetry scopes (ring span + profiler annotation,
+on its group's track): ``sched.await`` while it waits for an epoch with
+work and ``sched.finalize`` around each flush of finished records. The
+partitioner call (Tc1→Tc2) gets the profiler annotation ``sched.take``
+alone: the chunk's record already puts it in the ring as its
+``schedule`` phase.
 """
 from __future__ import annotations
 
@@ -173,12 +180,12 @@ class DynamicScheduler:
         self.chunk_mode = chunk_mode
         # always-on observability: None → the process-wide default
         # Telemetry; repro.telemetry.OFF → uninstrumented (the
-        # benchmarks/telemetry_overhead.py baseline). The dispatch hot
-        # path only *banks* finished completion batches (one GIL-atomic
-        # deque append per finalize batch); the per-record work —
-        # histograms, counters, chunk spans — runs in _tel_drain on the
-        # snapshot reader's thread, so instrumentation adds neither
-        # shared-lock contention nor per-chunk GIL pressure.
+        # benchmarks/telemetry_overhead.py baseline). On the dispatch hot
+        # path each scope is one deque append, and finished completion
+        # batches are banked (one append per finalize batch); the
+        # per-record work (histograms, counters, chunk spans) runs in
+        # _tel_drain on the snapshot reader's thread, so instrumentation
+        # adds no shared-lock contention.
         self.telemetry = telemetry_mod.resolve(telemetry)
         self._tel_group: Dict[str, tuple] = {}
         # banked (epoch_index, records) batches awaiting ingestion;
@@ -407,7 +414,9 @@ class DynamicScheduler:
         epoch: Optional[EpochHandle] = None
         try:
             while True:
-                epoch = self._await_epoch(name, idx)
+                with telemetry_mod.scope(self.telemetry, "sched.await", name,
+                                         group=name):
+                    epoch = self._await_epoch(name, idx)
                 if epoch is None:
                     break
                 idx = epoch.index + 1
@@ -526,9 +535,11 @@ class DynamicScheduler:
                 if self._preempt_rank < epoch.rank:
                     preempted = True        # a more urgent epoch has work:
                     break                   # drain the pipeline and jump
-                tc1 = self.clock()
-                token = part.next_token(name, space)
-                tc2 = self.clock()
+                with telemetry_mod.annotate(self.telemetry, "sched.take",
+                                            group=name, epoch=epoch.index):
+                    tc1 = self.clock()
+                    token = part.next_token(name, space)
+                    tc2 = self.clock()
                 if token is None:
                     break
                 rec = ChunkRecord(token, tc1=tc1, tc2=tc2)
@@ -637,17 +648,20 @@ class DynamicScheduler:
         the λ-tracker are already handled. Clears ``recs``."""
         if not recs:
             return
-        self.ledger.add_many(recs)
-        epoch.ledger.add_many(recs)
-        epoch._records.extend(recs)
-        if self.telemetry is not None:
-            # bank the batch for snapshot-time ingestion: one atomic
-            # append — the only telemetry cost on the dispatch hot path
-            pending = self._tel_pending
-            if len(pending) == pending.maxlen:
-                self._tel_lost += 1
-            pending.append((epoch.index, tuple(recs)))
-        del recs[:]
+        group = recs[0].token.group
+        with telemetry_mod.scope(self.telemetry, "sched.finalize", group,
+                                 group=group, epoch=epoch.index):
+            self.ledger.add_many(recs)
+            epoch.ledger.add_many(recs)
+            epoch._records.extend(recs)
+            if self.telemetry is not None:
+                # bank the batch for snapshot-time ingestion: one atomic
+                # append, the per-record work runs in _tel_drain
+                pending = self._tel_pending
+                if len(pending) == pending.maxlen:
+                    self._tel_lost += 1
+                pending.append((epoch.index, tuple(recs)))
+            del recs[:]
 
     def _tel_handles(self, group: str) -> tuple:
         """Per-group metric handles, bound once (registry get-or-create
@@ -658,8 +672,7 @@ class DynamicScheduler:
             h = self._tel_group[group] = (
                 reg.counter("sched.chunks", group=group),
                 reg.counter("sched.items", group=group),
-                reg.histogram("sched.chunk_host_s", group=group),
-                reg.histogram("sched.chunk_device_s", group=group))
+                reg.histogram("sched.chunk_host_s", group=group))
         return h
 
     def _tel_drain(self) -> None:
@@ -676,8 +689,7 @@ class DynamicScheduler:
                 epoch_idx, recs = pending.popleft()
             except IndexError:
                 break
-            chunks, items, host_h, dev_h = self._tel_handles(
-                recs[0].token.group)
+            chunks, items, host_h = self._tel_handles(recs[0].token.group)
             n = 0
             for rec in recs:
                 n += rec.token.chunk.size
@@ -686,7 +698,6 @@ class DynamicScheduler:
                                               else max(rec.tc3 - rec.tc2,
                                                        0.0))
                 host_h.observe(host)
-                dev_h.observe(rec.device_time)
                 tracer.chunk(rec, epoch_idx)
             chunks.add(len(recs))
             items.add(n)

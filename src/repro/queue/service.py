@@ -28,6 +28,12 @@ caught by the runtime Watchdog both flow to the AdmissionController as
 on_group_leave events, shrinking advertised capacity immediately; a
 StragglerDetector, when attached, derates a slowing group's advertised
 capacity *before* it is declared dead.
+
+The drain opens telemetry scopes (ring span + profiler annotation) on the
+``service`` track: ``svc.pop`` (one batch popped off the queue, DWRR pass
+included), ``svc.submit`` (jobs marked RUNNING, the epoch submitted),
+``svc.complete`` (a finished batch's job states, accounting and journal
+writes) and ``svc.wait`` (parked for work).
 """
 from __future__ import annotations
 
@@ -315,6 +321,9 @@ class JobService:
             h = self._tel[key] = self.telemetry.registry.histogram(
                 name, **labels)
         return h
+
+    def _scope(self, name: str, **ids):
+        return telemetry_mod.scope(self.telemetry, name, "service", **ids)
 
     def _collect(self) -> None:
         reg = self.telemetry.registry
@@ -826,6 +835,12 @@ class JobService:
                            submitted_at=ib.submitted_at,
                            finished_at=finished)
 
+    def _complete(self, ib: _InflightBatch) -> BatchReport:
+        """Finalize a finished in-flight batch (job states, accounting,
+        journal writes) inside its ``svc.complete`` scope."""
+        with self._scope("svc.complete", jobs=len(ib.jobs)):
+            return self._finalize_batch(ib)
+
     def _pump_express(self) -> bool:
         """Express lane: drain urgent-tier jobs PAST the pipeline-depth
         gate (up to ``express_slots`` extra batches in flight). The
@@ -871,11 +886,13 @@ class JobService:
         self._enforce_deadlines()
         while sum(1 for ib in self._inflight if not ib.express) \
                 < self.pipeline_depth:
-            jobs = self._pop_batch(0.0 if (self._inflight or progressed)
-                                   else block_s)
+            with self._scope("svc.pop"):
+                jobs = self._pop_batch(0.0 if (self._inflight or progressed)
+                                       else block_s)
             if not jobs:
                 break
-            rep = self._submit_batch(jobs)
+            with self._scope("svc.submit", jobs=len(jobs)):
+                rep = self._submit_batch(jobs)
             progressed = True
             self._pump_express()            # urgent work that arrived
             self._enforce_deadlines()       # while we blocked in pop
@@ -885,7 +902,7 @@ class JobService:
             if ib is not self._inflight[0] and ib.handle is not None \
                     and ib.handle.done():
                 self._inflight.remove(ib)
-                self._finalize_batch(ib)
+                self._complete(ib)
                 progressed = True
         while self._inflight:
             # block only when no new batch can be submitted anyway (full
@@ -894,7 +911,7 @@ class JobService:
             timeout = block_s if (full or not progressed) else 0.0
             if not self._inflight[0].handle.wait(timeout):
                 break
-            self._finalize_batch(self._inflight.popleft())
+            self._complete(self._inflight.popleft())
             progressed = True
         return progressed
 
@@ -905,7 +922,7 @@ class JobService:
         while self._inflight:
             ib = self._inflight.popleft()
             ib.handle.wait()
-            self._finalize_batch(ib)
+            self._complete(ib)
         jobs = self._pop_batch(block_s)
         if not jobs:
             return None
@@ -916,7 +933,7 @@ class JobService:
             return None                     # pop-to-dispatch window
         ib = self._inflight.popleft()
         ib.handle.wait()
-        return self._finalize_batch(ib)
+        return self._complete(ib)
 
     def run_until_idle(self, timeout_s: float = 60.0) -> bool:
         """Drain (pipelined) until queue + deferred + in-flight are empty;
@@ -933,7 +950,8 @@ class JobService:
                     idle = not self._deferred
                 if idle and self.queue.depth() == 0:
                     return True
-            self._wait_for_work(limit=deadline - self.clock())
+            with self._scope("svc.wait"):
+                self._wait_for_work(limit=deadline - self.clock())
         return False
 
     # -- daemon mode ---------------------------------------------------
@@ -956,7 +974,7 @@ class JobService:
             ib = self._inflight.popleft()
             if ib.handle is not None and not ib.handle.wait(10.0):
                 ib.error = TimeoutError("epoch unfinished at stop()")
-            self._finalize_batch(ib)
+            self._complete(ib)
 
     def close(self) -> None:
         """Stop the daemon (if running) and shut the runtime down."""
@@ -1036,7 +1054,8 @@ class JobService:
             self._check_brownout()
             if self._pump(block_s=0.0):
                 continue
-            self._wait_for_work()
+            with self._scope("svc.wait"):
+                self._wait_for_work()
 
 
 class _DoneHandle:

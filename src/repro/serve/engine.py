@@ -222,6 +222,13 @@ class HeteroServeEngine:
             return out
 
         counter = self._fail_counters.setdefault(key, {"n": 0})
+        tel = self.telemetry
+        if tel is not None:
+            # rows served and bucket padding, counted as chunks complete
+            real_rows = tel.registry.counter("exec.rows", group=key,
+                                             kind="real")
+            padded_rows = tel.registry.counter("exec.rows", group=key,
+                                               kind="padded")
 
         def step(batch):
             if g.fail_after_chunks is not None:
@@ -233,27 +240,37 @@ class HeteroServeEngine:
             prefill_fn, decode_fn = self._fns_for(b)
             if g.slowdown > 1.0:
                 time.sleep((g.slowdown - 1.0) * 0.001 * b)
-            logits, cache = prefill_fn(params, batch["tokens"],
-                                       batch.get("prefix_emb"))
-            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-            toks = [tok]
-            for _ in range(self.decode_tokens - 1):
-                logits, cache = decode_fn(params, cache, tok)
+            with self._scope("exec.prefill", key, group=key, rows=b):
+                logits, cache = prefill_fn(params, batch["tokens"],
+                                           batch.get("prefix_emb"))
                 tok = jnp.argmax(logits[:, -1], -1)[:, None] \
                     .astype(jnp.int32)
+            toks = [tok]
+            for _ in range(self.decode_tokens - 1):
+                with telemetry_mod.annotate(tel, "exec.decode", group=key,
+                                            rows=b):
+                    logits, cache = decode_fn(params, cache, tok)
+                    tok = jnp.argmax(logits[:, -1], -1)[:, None] \
+                        .astype(jnp.int32)
                 toks.append(tok)
             return jnp.concatenate(toks, axis=1), batch["rows"]
 
         def fetch(outs):
             gen, rows = outs
             tokens = np.asarray(gen)
+            rows = np.asarray(rows)
+            if tel is not None:
+                real = int(np.count_nonzero(rows >= 0))
+                real_rows.add(real)
+                padded_rows.add(rows.shape[0] - real)
             self._outputs.setdefault(key, GroupOutputs()).add(
-                np.asarray(rows), tokens, gen.devices())
+                rows, tokens, gen.devices())
             return {"tokens_out": tokens}
 
         return JaxChunkExecutor(step, make_inputs, fetch, device=device,
                                 async_depth=g.async_depth,
-                                priority_boost=g.priority_boost)
+                                priority_boost=g.priority_boost,
+                                telemetry=self._tel_arg())
 
     def _executor_for(self, g: GroupDef, namespace: str = "",
                       device=None) -> JaxChunkExecutor:
@@ -300,6 +317,9 @@ class HeteroServeEngine:
                                 adaptive_refill=self.adaptive_refill,
                                 telemetry=telemetry if telemetry is not None
                                 else self._tel_arg())
+
+    def _scope(self, name: str, tid: str, **ids):
+        return telemetry_mod.scope(self.telemetry, name, tid, **ids)
 
     def _tel_arg(self):
         """Forward the engine's resolved telemetry to a component ctor
@@ -381,55 +401,58 @@ class HeteroServeEngine:
         assert the event-driven drain isn't busy-polling.
         """
         self._outputs = {}
-        tracker = ThroughputTracker(self.alpha)
-        ledger = OverheadLedger()
-        ledger.keep_records = False           # bounded memory for long runs
-        dead: set = set()
+        with self._scope("serve.build", "serve"):
+            tracker = ThroughputTracker(self.alpha)
+            ledger = OverheadLedger()
+            ledger.keep_records = False       # bounded memory for long runs
+            dead: set = set()
 
-        def make_scheduler() -> DynamicScheduler:
-            # called once for the persistent runtime; again only if every
-            # group died (or per batch with persistent=False)
-            sched = self._build_scheduler(exclude=dead)
-            sched.tracker = tracker           # runtime-scoped λ / §3.3
-            sched.ledger = ledger
-            return sched
+            def make_scheduler() -> DynamicScheduler:
+                # called once for the persistent runtime; again only if
+                # every group died (or per batch with persistent=False)
+                sched = self._build_scheduler(exclude=dead)
+                sched.tracker = tracker           # runtime-scoped λ / §3.3
+                sched.ledger = ledger
+                return sched
 
-        accountant = None
-        if tenants is not None:
-            queue = ShardedQueueManager(tenants, telemetry=self._tel_arg())
-            accountant = TenantAccountant(tenants,
-                                          energy_model=energy_model)
-        else:
-            queue = QueueManager()
-        admission = None
-        # the gate also turns on when any tenant spec carries an SLO or
-        # quota — otherwise those contracts would be silently inert
-        # without a global --slo; with no global SLO the global delay
-        # band is infinite and only the per-tenant contracts bind
-        if slo_delay_s is not None or (tenants is not None
-                                       and tenants.any_gating()):
-            admission = AdmissionController(
-                queue, tracker, ledger,
-                slo_delay_s=slo_delay_s if slo_delay_s is not None
-                else float("inf"),
-                registry=tenants, telemetry=self._tel_arg(),
-                policy=policy)
-            for g in self.groups:
-                admission.on_group_join(g.name, 1.0)
-        journal = JournalStore(journal_path) if journal_path else None
-        service = JobService(make_scheduler, queue=queue,
-                             admission=admission, journal=journal,
-                             batch_jobs=batch_jobs,
-                             on_group_failed=dead.add,
-                             pipeline_depth=pipeline_depth,
-                             persistent=persistent,
-                             accountant=accountant,
-                             telemetry=self._tel_arg(),
-                             express=express)
+            accountant = None
+            if tenants is not None:
+                queue = ShardedQueueManager(tenants, telemetry=self._tel_arg())
+                accountant = TenantAccountant(tenants,
+                                              energy_model=energy_model)
+            else:
+                queue = QueueManager()
+            admission = None
+            # the gate also turns on when any tenant spec carries an SLO or
+            # quota — otherwise those contracts would be silently inert
+            # without a global --slo; with no global SLO the global delay
+            # band is infinite and only the per-tenant contracts bind
+            if slo_delay_s is not None or (tenants is not None
+                                           and tenants.any_gating()):
+                admission = AdmissionController(
+                    queue, tracker, ledger,
+                    slo_delay_s=slo_delay_s if slo_delay_s is not None
+                    else float("inf"),
+                    registry=tenants, telemetry=self._tel_arg(),
+                    policy=policy)
+                for g in self.groups:
+                    admission.on_group_join(g.name, 1.0)
+            journal = JournalStore(journal_path) if journal_path else None
+            service = JobService(make_scheduler, queue=queue,
+                                 admission=admission, journal=journal,
+                                 batch_jobs=batch_jobs,
+                                 on_group_failed=dead.add,
+                                 pipeline_depth=pipeline_depth,
+                                 persistent=persistent,
+                                 accountant=accountant,
+                                 telemetry=self._tel_arg(),
+                                 express=express)
         t0 = time.monotonic()
-        for job in jobs:
-            service.submit(job)
-        drained = service.run_until_idle(timeout_s=timeout_s)
+        with self._scope("serve.submit", "serve", jobs=len(jobs)):
+            for job in jobs:
+                service.submit(job)
+        with self._scope("serve.drain", "serve"):
+            drained = service.run_until_idle(timeout_s=timeout_s)
         dt = time.monotonic() - t0
         if idle_s > 0.0:
             # park the daemon on an empty queue: with the event-driven
@@ -437,9 +460,10 @@ class HeteroServeEngine:
             # ≤ 1/fallback_s per second (vs. 1/poll_s busy-polling)
             service.start()
             time.sleep(idle_s)
-        service.close()
-        if journal is not None:
-            journal.close()
+        with self._scope("serve.close", "serve"):
+            service.close()
+            if journal is not None:
+                journal.close()
         st = service.stats
         cancelled = sum(1 for j in jobs if j.state.value == "cancelled")
         done_items = sum(j.items for j in jobs if j.state.value == "done")
@@ -503,6 +527,7 @@ class HeteroServeEngine:
         """
         from repro.chaos import ChaosExecutor, ChaosInjector, FaultPlan
         from repro.federation import FederatedService
+        t_serve = time.monotonic()
         if journal_dir is None:
             journal_dir = tempfile.mkdtemp(prefix="repro-fed-")
         self._outputs = {}
@@ -571,29 +596,32 @@ class HeteroServeEngine:
                               accountant=accountant,
                               telemetry=telemetry, express=express)
 
-        fed = FederatedService(make_service, rids, journal_dir,
-                               tenants=tenants,
-                               telemetry=self._tel_arg(),
-                               heartbeat_s=heartbeat_s,
-                               chaos=chaos)
-        t0 = time.monotonic()
-        fed.start()
-        for job in jobs:
-            fed.submit(job)
+        with self._scope("serve.build", "serve", runtimes=len(rids)):
+            fed = FederatedService(make_service, rids, journal_dir,
+                                   tenants=tenants,
+                                   telemetry=self._tel_arg(),
+                                   heartbeat_s=heartbeat_s,
+                                   chaos=chaos)
+            t0 = time.monotonic()
+            fed.start()
+        with self._scope("serve.submit", "serve", jobs=len(jobs)):
+            for job in jobs:
+                fed.submit(job)
         victim = f"r{kill_runtime}" if kill_runtime is not None \
             and 0 <= kill_runtime < len(rids) else None
-        if victim is not None:
-            threshold = max(1, int(kill_after_frac * len(jobs)))
-            deadline = time.monotonic() + timeout_s
-            while time.monotonic() < deadline:
-                done = sum(1 for j in jobs
-                           if j.state.value in ("done", "failed",
-                                                "cancelled"))
-                if done >= threshold:
-                    break
-                time.sleep(0.01)
-            fed.kill_runtime(victim)
-        drained = fed.run_until_idle(timeout_s=timeout_s)
+        with self._scope("serve.drain", "serve"):
+            if victim is not None:
+                threshold = max(1, int(kill_after_frac * len(jobs)))
+                deadline = time.monotonic() + timeout_s
+                while time.monotonic() < deadline:
+                    done = sum(1 for j in jobs
+                               if j.state.value in ("done", "failed",
+                                                    "cancelled"))
+                    if done >= threshold:
+                        break
+                    time.sleep(0.01)
+                fed.kill_runtime(victim)
+            drained = fed.run_until_idle(timeout_s=timeout_s)
         rep = fed.report()
         rep.time_s = time.monotonic() - t0
         per_tenant: Dict[str, Dict] = {}
@@ -609,7 +637,13 @@ class HeteroServeEngine:
                 agg["busy_s"] += d["busy_s"]
                 agg["energy_j"] += d["energy_j"]
                 agg["batches"] += d["batches"]
-        fed.close()
+        with self._scope("serve.close", "serve"):
+            fed.close()
+        if self.telemetry is not None:
+            # the whole call, against which each runtime's epoch spans
+            # (``rK/epochs``) show how long it sat drained
+            self.telemetry.tracer.span("serve", "serve", t_serve,
+                                       time.monotonic(), runtimes=rids)
         return FederatedServeReport(
             fed=rep, drained=drained, per_tenant=per_tenant,
             new_tokens=sum(rep.per_tenant_items.values())

@@ -24,13 +24,14 @@ dispatch hot path stays within 1.15× of uninstrumented at 8 workers.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Any, Dict, Optional
 
 from repro.telemetry.registry import (Counter, Gauge, Histogram,
                                       LabeledRegistry, MetricsRegistry,
                                       format_key)
-from repro.telemetry.spans import LabeledTracer, SpanTracer
+from repro.telemetry.spans import LabeledTracer, SpanTracer, annotation
 from repro.telemetry.exporters import (MetricsExporter, prometheus_text,
                                        read_jsonl)
 
@@ -98,6 +99,26 @@ def default() -> Telemetry:
         return _default
 
 
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def scope(telemetry, name: str, tid: str, **ids):
+    """``telemetry.tracer.scope(name, tid, **ids)`` of a component's
+    resolved telemetry; a no-op where it runs uninstrumented (None)."""
+    if telemetry is None:
+        return _NO_SCOPE
+    return telemetry.tracer.scope(name, tid, **ids)
+
+
+def annotate(telemetry, name: str, **ids):
+    """The profiler half of a scope alone (``spans.annotation``), for a
+    site whose interval the ring already holds or that is too hot for a
+    ring entry; a no-op where the component runs uninstrumented."""
+    if telemetry is None:
+        return _NO_SCOPE
+    return annotation(name, **ids)
+
+
 def resolve(telemetry) -> Optional[Telemetry]:
     """Normalize a component's ``telemetry=`` argument: ``None`` → the
     always-on default, ``OFF``/``False`` → uninstrumented (None), an
@@ -112,6 +133,6 @@ def resolve(telemetry) -> Optional[Telemetry]:
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsExporter",
     "LabeledRegistry", "LabeledTracer", "SpanTracer", "Telemetry",
-    "TelemetryView", "OFF", "default", "resolve",
+    "TelemetryView", "OFF", "default", "resolve", "scope", "annotate",
     "prometheus_text", "read_jsonl", "format_key",
 ]

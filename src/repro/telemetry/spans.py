@@ -10,6 +10,16 @@ the epoch's chunks complete). Queue/scheduler *events* — admission
 decisions, DWRR picks, steals, refills, requeues, epoch submit/finalize —
 are instant events on the same timeline.
 
+Work sites inside the runtime (executor, scheduler, service, serve calls)
+open ``scope(name, tid, **ids)`` spans. Each is written twice: into the
+ring like any span, on ``time.monotonic`` with its ids and its parent
+(the scope open around it on the same thread), and, while a JAX profiler
+session is live, as a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, which lands on the profiler's host plane on the same
+clock as the device's operations. ``annotation(name, **ids)`` is the
+second half alone, for sites too hot for a ring entry (one per decode
+call) and for intervals a chunk's record already puts in the ring.
+
 Emission is designed for the dispatch hot path: a sampled chunk appends
 ONE compact tuple to a ``collections.deque(maxlen=...)`` (GIL-atomic,
 lock-free, bounded — old events fall off the front on overflow, counted);
@@ -27,7 +37,9 @@ spans on a sibling ``<group>/dev`` track, so pipelined executors
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -43,6 +55,51 @@ _CHUNK = 0        # chunk lifecycle (from a ChunkRecord)
 _SPAN = 1         # generic duration span (service batches, exports)
 _INSTANT = 2      # point event (steal, requeue, admission, epoch marks)
 
+#: name prefix of the runtime's profiler annotations
+ANNOTATION_PREFIX = "repro."
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **ids):
+    """A ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` carrying
+    ``ids``. A process that never imported JAX cannot have a profiler
+    session live, so there it is a no-op and JAX stays unimported."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **ids)
+
+
+class _Scope:
+    """One ``SpanTracer.scope``: the annotation while open, one ring span
+    at exit (also when the body raises)."""
+
+    __slots__ = ("tracer", "name", "tid", "ids", "start", "parent",
+                 "annotation")
+
+    def __init__(self, tracer: "SpanTracer", name: str, tid: str,
+                 ids: Dict[str, Any]):
+        self.tracer = tracer
+        self.name = name
+        self.tid = tid
+        self.ids = ids
+
+    def __enter__(self) -> "_Scope":
+        stack = self.tracer._scope_stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self.annotation = annotation(self.name, **self.ids)
+        self.annotation.__enter__()
+        self.start = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = clock()
+        self.annotation.__exit__(*exc)
+        self.tracer._scope_stack().pop()
+        self.tracer.span(self.name, self.tid, self.start, end,
+                         parent=self.parent, **self.ids)
+
 
 class SpanTracer:
     def __init__(self, sample_rate: float = 1.0,
@@ -57,6 +114,7 @@ class SpanTracer:
         self._epoch_tags: Dict[int, Dict[str, Any]] = {}
         self._max_epoch_tags = max_epoch_tags
         self._tag_lock = threading.Lock()
+        self._local = threading.local()     # per-thread open scope names
 
     # -- sampling -------------------------------------------------------
     def sampled(self, seq: int) -> bool:
@@ -99,6 +157,20 @@ class SpanTracer:
              **args) -> None:
         self.emitted += 1
         self._events.append((_SPAN, name, tid, start, end, args or None))
+
+    def scope(self, name: str, tid: str, **ids) -> _Scope:
+        """Context manager timing the work inside it as span ``name`` on
+        track ``tid``: a ring span (args: ``ids`` plus ``parent``, the
+        name of the scope open around it on this thread, or None) and a
+        profiler annotation ``repro.<name>`` with ``ids``."""
+        return _Scope(self, name, tid, ids)
+
+    def _scope_stack(self) -> List[str]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
 
     def instant(self, name: str, tid: str = "events",
                 ts: Optional[float] = None, **args) -> None:
@@ -232,6 +304,14 @@ class LabeledTracer:
     def instant(self, name: str, tid: str = "events",
                 ts: Optional[float] = None, **args) -> None:
         self.base.instant(name, f"{self.prefix}/{tid}", ts=ts, **args)
+
+    def scope(self, name: str, tid: str, **ids) -> _Scope:
+        """``SpanTracer.scope`` on this runtime's track ``<prefix>/tid``;
+        a tid that is already a namespaced group name (``r0/accel``) is
+        kept as it is."""
+        if not tid.startswith(self.prefix + "/"):
+            tid = f"{self.prefix}/{tid}"
+        return self.base.scope(name, tid, **ids)
 
     def __getattr__(self, name):
         return getattr(self.base, name)

@@ -242,6 +242,88 @@ def test_chrome_trace_structure_and_nesting():
         assert hi <= chunk["ts"] + chunk["dur"] + 1e-6
 
 
+def _scope_spans(tr):
+    return [e for e in tr.chrome_events() if e.get("ph") == "X"]
+
+
+def test_scope_writes_a_ring_span_with_ids_and_parent():
+    tr = SpanTracer()
+    with tr.scope("serve.drain", "serve"):
+        with tr.scope("svc.pop", "service", jobs=4):
+            time.sleep(0.001)
+        with pytest.raises(ValueError):
+            with tr.scope("svc.complete", "service", jobs=4):
+                raise ValueError("boom")        # the span still closes
+    spans = {e["name"]: e for e in _scope_spans(tr)}
+    assert set(spans) == {"serve.drain", "svc.pop", "svc.complete"}
+    assert spans["svc.pop"]["args"] == {"parent": "serve.drain", "jobs": 4}
+    assert spans["svc.complete"]["args"]["parent"] == "serve.drain"
+    assert spans["serve.drain"]["args"] == {"parent": None}
+    assert spans["svc.pop"]["dur"] >= 1000.0            # us
+    outer, inner = spans["serve.drain"], spans["svc.pop"]
+    assert outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    assert tr.emitted == 3 and tr.dropped == 0
+    # another thread's scopes have no parent from this one
+    with tr.scope("outer", "a"):
+        th = threading.Thread(target=lambda: tr.scope("other", "b")
+                              .__enter__().__exit__(None, None, None))
+        th.start()
+        th.join(timeout=10)
+    other = [e for e in _scope_spans(tr) if e["name"] == "other"]
+    assert other[0]["args"]["parent"] is None
+
+
+def test_labeled_scope_namespaces_its_track_once():
+    tel = Telemetry()
+    view = tel.labeled(runtime="r1")
+    with view.tracer.scope("svc.wait", "service"):
+        pass
+    with view.tracer.scope("sched.take", "r1/accel", group="r1/accel"):
+        pass
+    names = {e["tid"]: e["args"]["name"] for e in tel.tracer.chrome_events()
+             if e.get("name") == "thread_name"}
+    tracks = {e["name"]: names[e["tid"]]
+              for e in _scope_spans(tel.tracer)}
+    assert tracks == {"svc.wait": "r1/service", "sched.take": "r1/accel"}
+
+
+def test_scope_annotations_land_in_the_profiler_host_plane(tmp_path):
+    """Under a live profiler session the scopes (and the bare
+    annotations) appear on the trace's host plane, named ``repro.*``."""
+    import glob
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from repro.telemetry.spans import annotation
+
+    tr = SpanTracer()
+    x = jnp.ones((8, 8))
+    jax.block_until_ready(x @ x)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.scope("exec.prefill", "accel", group="accel", rows=8):
+            jax.block_until_ready(x @ x)
+            with annotation("exec.decode", group="accel"):
+                jax.block_until_ready(x + 1.0)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    found[ev.name] = plane.name
+    assert set(found) == {"repro.exec.prefill", "repro.exec.decode"}
+    assert all(p.startswith("/host:") for p in found.values())
+    # the ring holds the scope alone
+    assert [e["name"] for e in _scope_spans(tr)] == ["exec.prefill"]
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------
