@@ -14,7 +14,8 @@ Two causal schedules:
     computed, recovering the ~2× for long sequences.
 
 GQA layout convention: q is grouped as (b, s, g, m, hd) where g = n_kv_heads
-and m = n_heads // n_kv_heads; k/v are (b, s, g, hd).
+and m = n_heads // n_kv_heads; k/v are (b, s, g, hd), or (b, s, g * hd) for
+a decode cache (:func:`decode_attention`).
 """
 from __future__ import annotations
 
@@ -25,6 +26,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.sharding.partition import per_shard
+from repro.sharding.rules import Packed
 
 NEG_INF = -1e30
 
@@ -173,23 +177,49 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      kv_len: jax.Array) -> jax.Array:
-    """Single-position attention against a (padded) KV cache.
+    """Single-position attention against a (padded) KV cache whose kv heads
+    share one minor axis.
 
-    q: (b, 1, g, m, hd); caches: (b, S, g, hd); kv_len: scalar or (b,).
-    Unchunked: XLA/GSPMD partitions the softmax over a sequence-sharded cache
-    (flash-decode-style partial merge) without help.
+    q: (b, 1, g, m, hd); caches: (b, S, g * hd); kv_len: scalar or (b,).
+    Both products contract the packed axis or the sequence, each as one
+    matrix product per row, so the caches are read in the layout they are
+    stored in: q is spread block-diagonally over the g groups, and each
+    head keeps its own group's slice of the output. A head_dim below the
+    TPU's 128 lanes never becomes a minor axis of a cache-sized operand.
+
+    Under a mesh each shard attends with its own rows and kv heads
+    (:func:`~repro.sharding.partition.per_shard`), so no product sums
+    across shards; the packed axis shards whole heads only. A cache
+    sharded along the sequence is left to GSPMD, which merges the softmax
+    partials (flash-decode style).
     """
+    b, _, g = q.shape[:3]
+    kv = Packed("cache_kv_heads", g)
+    q_axes = ("cache_batch", None, kv, None, None)
+    cache_axes = ("cache_batch", None, kv)
+    kv_len = jnp.broadcast_to(jnp.asarray(kv_len), (b,))
+    return per_shard(_decode_attention,
+                     (q_axes, cache_axes, cache_axes, ("cache_batch",)),
+                     q_axes)(q, k_cache, v_cache, kv_len)
+
+
+def _decode_attention(q, k_cache, v_cache, kv_len):
     b, _, g, m, hd = q.shape
     S = k_cache.shape[1]
     scale = 1.0 / math.sqrt(hd)
-    s = jnp.einsum("bqgmh,bkgh->bgmqk", q, k_cache,
+    own = jnp.eye(g, dtype=bool)
+    q_bd = jnp.where(own[:, None, :, None], q[:, 0, :, :, None], 0)
+    q_bd = q_bd.reshape(b, g * m, g * hd)
+    s = jnp.einsum("bjc,bkc->bjk", q_bd, k_cache,
                    preferred_element_type=jnp.float32) * scale
-    mask = jnp.arange(S)[None, :] < jnp.reshape(jnp.asarray(kv_len), (-1, 1))
-    s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
+    mask = jnp.arange(S)[None, :] < kv_len[:, None]
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bgmqk,bkgh->bqgmh", p.astype(v_cache.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    o = jnp.einsum("bjk,bkc->bjc", p.astype(v_cache.dtype), v_cache,
+                   preferred_element_type=jnp.float32)
+    # each head's own group: a select and a sum over zeros, exact
+    o = jnp.where(own[:, None, :, None], o.reshape(b, g, m, g, hd), 0)
+    return o.sum(axis=3)[:, None].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

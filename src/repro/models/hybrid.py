@@ -193,7 +193,8 @@ def decode_step(cfg: LMConfig, params: Dict, cache: Dict, tokens: jax.Array):
         v_cache = lshard(v_cache, "cache_batch", "cache_seq",
                          "cache_kv_heads", None)
         qg = group_query_heads(q, cfg.n_kv_heads)
-        o = decode_attention(qg, k_cache, v_cache, pos + 1)
+        o = decode_attention(qg, k_cache.reshape(*k_cache.shape[:2], -1),
+                             v_cache.reshape(*v_cache.shape[:2], -1), pos + 1)
         o = jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), bp["attn"]["wo"])
         x = x + o
         x, _ = tfm.ffn_block_fwd(cfg, bp, x)

@@ -17,7 +17,8 @@ from repro.models.attention import (chunked_attention, decode_attention,
                                     group_query_heads, ungroup_heads)
 from repro.models.layers import (ParamDef, apply_rope, mlp_defs, mlp_fwd,
                                  norm, norm_defs, rope_freqs)
-from repro.sharding.partition import lshard
+from repro.sharding.partition import lshard, per_shard
+from repro.sharding.rules import Packed
 
 
 # ---------------------------------------------------------------------------
@@ -200,11 +201,12 @@ def prefill(cfg: LMConfig, params: Dict, tokens: jax.Array,
         o = jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), bp["attn"]["wo"])
         x = x + lshard(o, "act_batch", "act_res_seq", "act_embed")
         x, _ = ffn_block_fwd(cfg, bp, x)
+        k, v = k.reshape(b, s, -1), v.reshape(b, s, -1)
         if S > s:
-            pad = [(0, 0), (0, S - s), (0, 0), (0, 0)]
+            pad = [(0, 0), (0, S - s), (0, 0)]
             k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        k = lshard(k, "cache_batch", "cache_seq", "cache_kv_heads", None)
-        v = lshard(v, "cache_batch", "cache_seq", "cache_kv_heads", None)
+        k = lshard(k, "cache_batch", "cache_seq", _kv_axis(cfg))
+        v = lshard(v, "cache_batch", "cache_seq", _kv_axis(cfg))
         return x, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
@@ -216,8 +218,14 @@ def prefill(cfg: LMConfig, params: Dict, tokens: jax.Array,
 
 
 def init_cache(cfg: LMConfig, batch: int, max_len: int, abstract: bool = False):
-    g, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (cfg.n_layers, batch, max_len, g, hd)
+    """Keys and values as (layers, batch, max_len, n_kv_heads * head_dim).
+
+    Heads and head_dim share one minor axis: with a head_dim below the
+    TPU's 128 lanes a separate head_dim axis is stored sequence-minor, and
+    every layer's slice is then relaid out to be read and written.
+    """
+    shape = (cfg.n_layers, batch, max_len,
+             cfg.n_kv_heads * cfg.resolved_head_dim)
     dt = cfg.activation_dtype
     if abstract:
         mk = lambda s, d: jax.ShapeDtypeStruct(s, d)
@@ -227,13 +235,33 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, abstract: bool = False):
             "pos": mk((batch,), jnp.int32)}
 
 
+def _kv_axis(cfg: LMConfig) -> Packed:
+    """The cache's packed axis shards whole kv heads only."""
+    return Packed("cache_kv_heads", cfg.n_kv_heads)
+
+
 def cache_axes(cfg: LMConfig):
-    ax = ("layers", "cache_batch", "cache_seq", "cache_kv_heads", None)
+    ax = ("layers", "cache_batch", "cache_seq", _kv_axis(cfg))
     return {"k": ax, "v": ax, "pos": ("cache_batch",)}
 
 
+def write_kv(cfg: LMConfig, cache: jax.Array, new: jax.Array, i,
+             pos: jax.Array) -> jax.Array:
+    """Layer ``i``'s new keys or values, (b, n_kv_heads * head_dim), into
+    the stacked cache: one write per row, at the row's own position, on
+    each shard's own rows and heads under a mesh."""
+    ax = ("layers", "cache_batch", None, _kv_axis(cfg))
+    put = lambda c, n, layer, p: c.at[layer, jnp.arange(c.shape[1]), p].set(n)
+    return per_shard(put, (ax, ("cache_batch", _kv_axis(cfg)), None,
+                           ("cache_batch",)), ax)(cache, new, i, pos)
+
+
 def decode_step(cfg: LMConfig, params: Dict, cache: Dict, tokens: jax.Array):
-    """One decode step. tokens: (b, 1). Returns (logits, new_cache)."""
+    """One decode step. tokens: (b, 1). Returns (logits, new_cache).
+
+    The stacked cache rides in the layer loop's carry and each layer writes
+    only its row's new position, so a donated cache is updated in place.
+    """
     b = tokens.shape[0]
     pos = cache["pos"]                                   # (b,)
     x = jnp.take(params["embed"], tokens, axis=0)        # (b, 1, d)
@@ -245,8 +273,12 @@ def decode_step(cfg: LMConfig, params: Dict, cache: Dict, tokens: jax.Array):
     inv, rot = rope_freqs(cfg.resolved_head_dim, cfg.rope_fraction,
                           cfg.rope_theta)
 
-    def body(x, inp):
-        bp, k_cache, v_cache = inp
+    def layer_kv(c, i):
+        c = jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False)
+        return lshard(c, "cache_batch", "cache_seq", _kv_axis(cfg))
+
+    def body(carry, bp, i):
+        x, ck, cv = carry
         h = norm(x, bp["attn_norm"], cfg.norm_type, cfg.norm_eps)
         q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"])
         k = jnp.einsum("bsd,dgk->bsgk", h, bp["attn"]["wk"])
@@ -254,36 +286,25 @@ def decode_step(cfg: LMConfig, params: Dict, cache: Dict, tokens: jax.Array):
         if cfg.pos_emb == "rope":
             q = apply_rope(q, positions, inv, rot)
             k = apply_rope(k, positions, inv, rot)
-        # in-place cache update at per-sequence position
-        upd = lambda c, new: jax.vmap(
-            lambda cb, nb, pb: jax.lax.dynamic_update_slice_in_dim(
-                cb, nb, pb, axis=0))(c, new, pos)
-        k_cache = upd(k_cache, k)
-        v_cache = upd(v_cache, v)
-        k_cache = lshard(k_cache, "cache_batch", "cache_seq",
-                         "cache_kv_heads", None)
-        v_cache = lshard(v_cache, "cache_batch", "cache_seq",
-                         "cache_kv_heads", None)
+        ck = write_kv(cfg, ck, k.reshape(b, -1), i, pos)
+        cv = write_kv(cfg, cv, v.reshape(b, -1), i, pos)
         qg = group_query_heads(q, cfg.n_kv_heads)
-        o = decode_attention(qg, k_cache, v_cache, pos + 1)
+        o = decode_attention(qg, layer_kv(ck, i), layer_kv(cv, i), pos + 1)
         o = jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), bp["attn"]["wo"])
         x = x + lshard(o, "act_batch", "act_res_seq", "act_embed")
         x, _ = ffn_block_fwd(cfg, bp, x)
-        return x, (k_cache, v_cache)
+        return x, ck, cv
 
+    carry = (x, cache["k"], cache["v"])
     if cfg.decode_unroll:
-        ck, cv = cache["k"], cache["v"]
         for i in range(cfg.n_layers):
             bp = jax.tree.map(lambda a: a[i], params["blocks"])
-            ki = jax.lax.dynamic_index_in_dim(ck, i, 0, keepdims=False)
-            vi = jax.lax.dynamic_index_in_dim(cv, i, 0, keepdims=False)
-            x, (ki, vi) = body(x, (bp, ki, vi))
-            ck = jax.lax.dynamic_update_index_in_dim(ck, ki, i, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(cv, vi, i, 0)
-        ks, vs = ck, cv
+            carry = body(carry, bp, i)
     else:
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"],
-                                             cache["k"], cache["v"]))
+        carry, _ = jax.lax.scan(
+            lambda c, inp: (body(c, *inp), None), carry,
+            (params["blocks"], jnp.arange(cfg.n_layers)))
+    x, ks, vs = carry
     x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = logits_fwd(cfg, params, x)
     return logits, {"k": ks, "v": vs, "pos": pos + 1}

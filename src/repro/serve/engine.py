@@ -14,6 +14,7 @@ import tempfile
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -170,7 +171,9 @@ class HeteroServeEngine:
             return M.prefill(cfg, params, tokens, prefix,
                              max_len=self.max_len)
 
-        @jax.jit
+        # the cache is donated: each call writes its new position in
+        # place, and the caller rebinds the cache it gets back
+        @partial(jax.jit, donate_argnums=(1,))
         def decode_fn(params, cache, tokens):
             return M.decode_step(cfg, params, cache, tokens)
 

@@ -1,8 +1,8 @@
 from repro.sharding.rules import ShardingRules, DEFAULT_RULES, \
-    LONG_CONTEXT_OVERRIDES, tree_shardings
-from repro.sharding.partition import lshard, use_mesh_rules, active_mesh, \
-    active_rules
+    LONG_CONTEXT_OVERRIDES, Packed, tree_shardings
+from repro.sharding.partition import lshard, per_shard, use_mesh_rules, \
+    active_mesh, active_rules
 
 __all__ = ["ShardingRules", "DEFAULT_RULES", "LONG_CONTEXT_OVERRIDES",
-           "tree_shardings", "lshard", "use_mesh_rules", "active_mesh",
-           "active_rules"]
+           "Packed", "tree_shardings", "lshard", "per_shard",
+           "use_mesh_rules", "active_mesh", "active_rules"]

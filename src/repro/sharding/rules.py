@@ -19,6 +19,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AxisMap = Union[None, str, Tuple[str, ...]]
 
 
+@dataclass(frozen=True)
+class Packed:
+    """A dimension that packs ``blocks`` equal blocks of one logical axis,
+    such as a cache's (n_kv_heads * head_dim) axis: it is sharded only
+    where each shard holds whole blocks."""
+    name: str
+    blocks: int
+
+
 def _as_tuple(v: AxisMap) -> Tuple[str, ...]:
     if v is None:
         return ()
@@ -99,7 +108,7 @@ class ShardingRules:
         return self
 
     # ------------------------------------------------------------------
-    def spec(self, mesh: Mesh, axes: Sequence[Optional[str]],
+    def spec(self, mesh: Mesh, axes: Sequence[Union[None, str, Packed]],
              shape: Sequence[int]) -> P:
         """PartitionSpec for a tensor with given logical axes and shape."""
         if len(axes) != len(shape):
@@ -108,6 +117,8 @@ class ShardingRules:
         used: set = set()
         out = []
         for dim, name in zip(shape, axes):
+            if isinstance(name, Packed):
+                name, dim = name.name, name.blocks
             entry: AxisMap = self.table.get(name) if name else None
             cand = tuple(a for a in _as_tuple(entry)
                          if a in mesh_sizes and a not in used)

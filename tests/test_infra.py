@@ -12,7 +12,7 @@ from repro.core import (Chunk, ChunkRecord, DeviceKind, GroupSpec,
                         ThroughputTracker, Token)
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLMData
 from repro.runtime import StragglerDetector, Watchdog
-from repro.sharding.rules import ShardingRules
+from repro.sharding.rules import Packed, ShardingRules
 from jax.sharding import PartitionSpec as P
 
 
@@ -142,6 +142,17 @@ def test_long_context_overrides():
     spec = r.spec(mesh, ("cache_batch", "cache_seq", "cache_kv_heads", None),
                   (1, 524288, 32, 64))
     assert spec == P(None, ("pod", "data"), "model")
+
+
+@pytest.mark.parametrize("blocks,spec", [(32, P("data", None, "model")),
+                                         (4, P("data"))])
+def test_rules_packed_axis_shards_whole_blocks(blocks, spec):
+    r = ShardingRules()
+    mesh = FakeMesh((16, 16), ("data", "model"))
+    # a (batch, seq, kv_heads * 128) cache: 32 heads split 2 per shard;
+    # 4 heads do not split over 16, though their 512 lanes would
+    axes = ("cache_batch", "cache_seq", Packed("cache_kv_heads", blocks))
+    assert r.spec(mesh, axes, (128, 32768, blocks * 128)) == spec
 
 
 # ---------------------------------------------------------------------------

@@ -52,13 +52,99 @@ def test_kv_len_masking():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_decode_attention_matches_reference():
-    q, k, v = qkv(sq=1, skv=40)
+@pytest.mark.parametrize("g,m", [(2, 2), (4, 1), (1, 4), (3, 2)])
+def test_decode_attention_matches_reference(g, m):
+    """Caches packed as (b, S, g * hd), per-row lengths."""
+    q, k, v = qkv(sq=1, skv=40, g=g, m=m)
     kv_len = jnp.array([17, 40])
-    out = decode_attention(q, k, v, kv_len)
+    b, s = k.shape[:2]
+    out = decode_attention(q, k.reshape(b, s, -1), v.reshape(b, s, -1),
+                           kv_len)
     ref = reference_attention(q, k, v, causal=False, kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# Run in a process of its own: the mesh needs 8 host devices, and every
+# other test sees one.
+_MESH_DECODE = r"""
+import json, re, sys
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs.registry import get_reduced_config
+from repro.launch.mesh import make_mesh_from
+from repro.models import model as M
+from repro.models import transformer as T
+from repro.models.attention import decode_attention
+from repro.sharding import ShardingRules, tree_shardings, use_mesh_rules
+
+kv_heads = int(sys.argv[1])
+mesh = make_mesh_from(jax.devices(), (2, 4), ("data", "model"))
+rules = ShardingRules()
+cfg = get_reduced_config("yi-6b").replace(dtype="float32", n_heads=8,
+                                          n_kv_heads=kv_heads)
+key = jax.random.PRNGKey(3)
+params = M.init_params(cfg, key)
+tokens = jax.random.randint(key, (4, 8), 0, cfg.vocab)
+_, cache = M.prefill(cfg, params, tokens, max_len=16)
+cache = dict(cache, pos=jnp.array([8, 3, 6, 1], jnp.int32))
+tok = tokens[:, -1:]
+want, want_cache = M.decode_step(cfg, params, cache, tok)
+c_sh = tree_shardings(mesh, jax.eval_shape(lambda: cache), M.cache_axes(cfg),
+                      rules)
+p_sh = tree_shardings(mesh, jax.eval_shape(lambda: params),
+                      M.param_axes(cfg), rules)
+with use_mesh_rules(mesh, rules):
+    got, got_cache = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t),
+                             in_shardings=(p_sh, c_sh, None))(params, cache,
+                                                              tok)
+    # one layer's attention and cache write, operands sharded as the step
+    # shards them
+    heads = c_sh["k"].update(spec=c_sh["k"].spec[1:])
+    sds = lambda shape, sh, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=sh)
+    c = kv_heads * 16
+    attend = jax.jit(decode_attention).lower(
+        sds((4, 1, kv_heads, 8 // kv_heads, 16), heads),
+        sds((4, 16, c), heads), sds((4, 16, c), heads),
+        sds((4,), c_sh["pos"], jnp.int32)).compile().as_text()
+    write = jax.jit(lambda *a: T.write_kv(cfg, *a)).lower(
+        sds(cache["k"].shape, c_sh["k"]),
+        sds((4, c), heads.update(spec=heads.spec[:1] + heads.spec[2:])),
+        jnp.int32(1), sds((4,), c_sh["pos"], jnp.int32)).compile().as_text()
+collective = (r"= \S+ (all-gather|all-reduce|reduce-scatter|all-to-all|"
+              r"collective-permute)\(")
+print(json.dumps({
+    "cache_spec": [str(a) for a in c_sh["k"].spec],
+    "logit_gap": float(jnp.abs(got - want).max()),
+    "cache_gap": float(jnp.abs(got_cache["k"] - want_cache["k"]).max()),
+    "collectives": re.findall(collective, attend + write),
+    "ops": [len(re.findall(r" = ", t)) for t in (attend, write)]}))
+"""
+
+
+@pytest.mark.parametrize("kv_heads,spec", [
+    (4, ["None", "data", "None", "model"]),   # one kv head per model shard
+    (2, ["None", "data"])])                   # 2 heads over 4: replicated
+def test_decode_under_tensor_parallel_mesh(kv_heads, spec):
+    """On a (data 2, model 4) mesh the packed cache shards whole kv heads
+    only, attention and the cache write run on each shard's own rows and
+    heads with no collective, and the step gives the logits and cache of
+    the unmeshed step."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    out = subprocess.run([sys.executable, "-c", _MESH_DECODE, str(kv_heads)],
+                         env=env, capture_output=True, text=True,
+                         timeout=300, check=True)
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["cache_spec"] == spec
+    assert got["logit_gap"] < 1e-4 and got["cache_gap"] < 1e-4, got
+    assert min(got["ops"]) > 0
+    assert got["collectives"] == [], got
 
 
 def test_moe_matches_dense_oracle_at_high_capacity():
@@ -107,6 +193,71 @@ def test_decode_unroll_matches_scan_decode():
     np.testing.assert_allclose(np.asarray(c_scan["k"]),
                                np.asarray(c_unroll["k"]), rtol=1e-5,
                                atol=1e-5)
+
+
+# stablelm-1.6b's shape of attention (one query head per kv head) and
+# yi-6b's grouped queries, through the layer loop and unrolled
+@pytest.mark.parametrize("arch,kv_heads,unroll", [
+    ("stablelm-1.6b", 4, False), ("stablelm-1.6b", 4, True),
+    ("yi-6b", None, False), ("yi-6b", None, True)])
+def test_ragged_decode_matches_forward(arch, kv_heads, unroll):
+    """Rows at different positions each write and read their own: row r
+    decodes from position ``s - back[r]``, which is the forward pass over
+    its first ``s - back[r]`` tokens followed by the decoded ones."""
+    cfg = get_reduced_config(arch).replace(dtype="float32",
+                                           decode_unroll=unroll)
+    if kv_heads:
+        cfg = cfg.replace(n_kv_heads=kv_heads)
+    params = M.init_params(cfg, KEY)
+    s, steps, back = 12, 3, np.array([0, 5, 2])
+    tokens = jax.random.randint(KEY, (len(back), s + steps), 0, cfg.vocab)
+    _, cache = M.prefill(cfg, params, tokens[:, :s], max_len=32)
+    assert cache["k"].shape == (cfg.n_layers, len(back), 32,
+                                cfg.n_kv_heads * cfg.resolved_head_dim)
+    cache = dict(cache, pos=jnp.asarray(s - back, jnp.int32))
+    got = []
+    for t in range(steps):
+        rows = [tokens[r, s - back[r] + t] for r in range(len(back))]
+        lg, cache = M.decode_step(cfg, params, cache,
+                                  jnp.stack(rows)[:, None])
+        got.append(np.asarray(lg[:, 0]))
+    np.testing.assert_array_equal(np.asarray(cache["pos"]), s - back + steps)
+    for r in range(len(back)):
+        n = s - back[r] + steps
+        want, _ = M.forward(cfg, params, tokens[r:r + 1, :n])
+        for t in range(steps):
+            np.testing.assert_allclose(got[t][r],
+                                       np.asarray(want[0, n - steps + t]),
+                                       rtol=2e-4, atol=2e-4)
+
+
+def test_engine_decode_donates_cache():
+    """The engine's decode program takes its cache by donation and gives
+    the tokens an undonated call of the same step gives."""
+    from repro.core.types import DeviceKind
+    from repro.serve.engine import HeteroServeEngine
+    from repro.train.trainer import GroupDef
+
+    cfg = get_reduced_config("stablelm-1.6b")
+    eng = HeteroServeEngine(cfg, [GroupDef("accel", DeviceKind.ACCEL)],
+                            prompt_len=8, decode_tokens=4)
+    prefill_fn, decode_fn = eng._fns_for(2)
+    step = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
+    tokens = np.stack([eng._prompt(i) for i in range(2)])
+    logits, cache = prefill_fn(eng.params, tokens, None)
+    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+    kept = jax.tree.map(jnp.copy, cache)
+    want = tok
+    for _ in range(eng.decode_tokens - 1):
+        given = cache
+        logits, cache = decode_fn(eng.params, cache, tok)
+        assert given["k"].is_deleted() and given["v"].is_deleted()
+        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        ref_logits, kept = step(eng.params, kept, want)
+        want = jnp.argmax(ref_logits[:, -1], -1)[:, None].astype(jnp.int32)
+        np.testing.assert_array_equal(np.asarray(tok), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(cache["k"]),
+                                  np.asarray(kept["k"]))
 
 
 def test_ssd_scan_matches_recurrence():

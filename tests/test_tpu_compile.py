@@ -10,6 +10,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,6 +83,53 @@ def test_stablelm_serve_step_compiles_for_v5e(one_chip, batch):
     cache = _on(one_chip, jax.eval_shape(prefill, params, prompts)[1])
     tok = _on(one_chip, jax.ShapeDtypeStruct((batch, 1), jnp.int32))
     _fits(jax.jit(decode).lower(params, cache, tok).compile())
+
+
+def _unfused_ops(hlo: str):
+    """The fused computations' names, and (computation, opcode, element
+    count) of each array-valued op that no fusion holds."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    comp, out = None, []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                      line)
+        if op and comp not in fused:
+            dims = [int(d) for d in op.group(1).split(",") if d]
+            out.append((comp, op.group(2), math.prod(dims)))
+    return fused, out
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b"])
+def test_decode_updates_cache_in_place_on_v5e(one_chip, arch):
+    """The engine's decode program (cache donated) on the chat cells'
+    shapes: the donated cache is written in place, and no layer's slice of
+    it is copied, as happens when the stored layout and the one the layer
+    reads and writes differ (head_dim 64 below the 128 lanes)."""
+    cfg = get_config(arch)
+    batch, max_len = 8, bucket(128 + 64)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on(one_chip, M.init_cache(cfg, batch, max_len, abstract=True))
+    tok = _on(one_chip, jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    compiled = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  tok).compile()
+    layer = batch * max_len * cfg.n_kv_heads * cfg.resolved_head_dim
+    fused, ops = _unfused_ops(compiled.as_text())
+    # the parser read this text: fused computations and the fusions
+    # that call them
+    assert fused and any(o[1] == "fusion" for o in ops)
+    big = [o for o in ops if o[1] == "copy" and o[2] >= layer]
+    assert not big, big
+    kv_bytes = sum(cache[n].size * cache[n].dtype.itemsize
+                   for n in ("k", "v"))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= kv_bytes
+    assert ma.temp_size_in_bytes < 0.05 * kv_bytes, ma.temp_size_in_bytes
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
