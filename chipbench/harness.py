@@ -25,7 +25,7 @@ import numpy as np
 from chipbench import spec as spec_mod
 from chipbench import traffic as traffic_mod
 from chipbench import work as work_mod
-from chipbench.spec import HERE, ROOT, Sizes, SpecError
+from chipbench.spec import HERE, ROOT, SpecError, family_of
 from chipbench.trace import SPAN_PREFIX
 
 PEAKS = HERE / "peaks.json"
@@ -89,6 +89,8 @@ class Run:
     work: Dict[str, work_mod.Work] = field(default_factory=dict)
     memory_peak_bytes: int = 0
     per_runtime_items: List[Dict[str, float]] = field(default_factory=list)
+    wave_s: List[float] = field(default_factory=list)  # each serve call
+    group_items: Counter = field(default_factory=Counter)
 
 
 def load_reader(name: str):
@@ -128,11 +130,11 @@ def _program():
     import repro  # noqa: F401  (fails where the checkout has no program)
 
 
-def _check_sizes(cfg, sizes: Sizes) -> None:
-    have = sizes_of(cfg)
+def _check_sizes(cfg, sizes) -> None:
+    have = family_of(sizes).sizes_of(cfg)
     if have != sizes:
         diff = {f: (getattr(have, f), getattr(sizes, f))
-                for f in Sizes.__dataclass_fields__
+                for f in type(sizes).__dataclass_fields__
                 if getattr(have, f) != getattr(sizes, f)}
         raise SpecError(f"{cfg.arch_id}: the program's sizes differ from "
                         f"the configuration file's (program, file): {diff}")
@@ -146,13 +148,6 @@ def _device_peak_bytes(devices) -> int:
 def _job_record(j) -> JobRecord:
     return JobRecord(j.created_at, j.first_started_at, j.finished_at,
                      j.state.value == "done", j.items)
-
-
-def sizes_of(cfg) -> Sizes:
-    """The program's config as the benchmark's ``Sizes``."""
-    d = {f: getattr(cfg, f) for f in Sizes.__dataclass_fields__}
-    d["head_dim"] = cfg.resolved_head_dim
-    return Sizes(**d)
 
 
 def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
@@ -205,13 +200,14 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = clock_mod.CompileClock(jax)
 
-    cfg = get_config(conf.arch).replace(**conf.reduced)
+    family = family_of(conf.sizes)
+    cfg = family.program_config(get_config(conf.arch), conf.reduced)
     _check_sizes(cfg, conf.sizes)
     sizes = conf.sizes
     if rehearse:
         from repro.configs.base import reduced
         cfg = reduced(cfg)
-        sizes = sizes_of(cfg)
+        sizes = family.sizes_of(cfg)
     weights_mod.check_layout(sizes, M.abstract_params(cfg))
     params = weights_mod.make_params(sizes, seed, dev0)
 
@@ -262,6 +258,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     log(f"window {run.window_s:.3f} s, {run.waves} waves, "
         f"{len(run.jobs)} jobs, {run.generated_tokens} tokens; programs "
         f"compiled or loaded in the window: {in_window}")
+    log("wave seconds: " + " ".join(f"{s:.3f}" for s in run.wave_s)
+        + f"; items per group: {dict(run.group_items)}")
 
     if trace:
         run.trace = profiler.summary()
@@ -350,8 +348,10 @@ def _traced_work(run: Run, sizes, prompt_len: int) \
         -> Dict[str, work_mod.Work]:
     """Work of the chunks that ran inside the profiled window, by
     program: ``prefill`` and ``decode`` calls at each chunk's real item
-    count and the tokens per sequence the serve call returned."""
+    count and the tokens per sequence the serve call returned, as the
+    family counts them."""
     lo, hi = run.trace.host_window
+    chunk = family_of(sizes).chunk
     totals = {"prefill": work_mod.ZERO, "decode": work_mod.ZERO}
     for ev in run.spans:
         if ev.get("cat") != "chunk":
@@ -360,8 +360,8 @@ def _traced_work(run: Run, sizes, prompt_len: int) \
         end = start + ev["dur"] * 1e-6
         if start < lo or end > hi:
             continue
-        pre, dec = work_mod.chunk(sizes, ev["args"]["items"], prompt_len,
-                                  run.tokens_per_sequence)
+        pre, dec = chunk(sizes, ev["args"]["items"], prompt_len,
+                         run.tokens_per_sequence)
         totals["prefill"] += pre
         totals["decode"] += dec
     return totals
@@ -384,6 +384,7 @@ def _serve_waves(eng, tr, registry, waves, seconds, profiler, run):
                         tier="standard", tenant=t)
                     for i, t in enumerate(waves.tenants(run.waves))]
         with jprof.TraceAnnotation(SPAN_PREFIX + "wave_serve"):
+            t_wave = time.perf_counter()
             if tr.runtimes == 1:
                 rep = eng.serve_jobs(jobs, batch_jobs=tr.batch_jobs,
                                      pipeline_depth=tr.pipeline_depth,
@@ -392,6 +393,9 @@ def _serve_waves(eng, tr, registry, waves, seconds, profiler, run):
                 rep = eng.serve_jobs_federated(
                     jobs, runtimes=tr.runtimes, batch_jobs=tr.batch_jobs,
                     pipeline_depth=tr.pipeline_depth, tenants=registry)
+            run.wave_s.append(time.perf_counter() - t_wave)
+            for g, n in getattr(rep, "per_group_items", {}).items():
+                run.group_items[g] += n
         with jprof.TraceAnnotation(SPAN_PREFIX + "wave_collect"):
             jobs_all.extend(jobs)
             for out in rep.outputs.values():
@@ -416,7 +420,7 @@ def _check(samples, seed, sizes, tr, control: bool):
     every token is in the vocabulary, (``control``) the fp8 control's
     widest gap on the same sequences, and how many distinct sequences
     there were to draw from."""
-    from chipbench.reference import Reference, control_gaps, served_gaps
+    from chipbench.reference import control_gaps, served_gaps
 
     rng = np.random.default_rng([seed, 7])
     # the engine makes request row i's prompt from (seed, i) in every
@@ -436,8 +440,16 @@ def _check(samples, seed, sizes, tr, control: bool):
                                            tr.prompt_len) for i in pick])
     served = np.stack([pool[i][1] for i in pick]).astype(np.int32)
     in_vocab = bool(((served >= 0) & (served < sizes.vocab)).all())
-    ref = Reference(sizes, seed)
+    ref = family_of(sizes).Reference(sizes, seed)
     widest = float(served_gaps(ref, prompts, served).max())
     control_gap = float(control_gaps(ref, prompts, served).max()) \
         if control else None
     return widest, in_vocab, control_gap, len(pool)
+
+
+def __getattr__(name):
+    """The dense family's ``sizes_of``, under the name it had here."""
+    if name == "sizes_of":
+        from chipbench.families.dense import sizes_of
+        return sizes_of
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

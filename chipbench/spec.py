@@ -1,15 +1,20 @@
 """Finds a cell's configuration, traffic mix and metrics by name.
 
 ``BENCHMARK.json`` at the root of the checkout names them; each is a file
-of its own under this directory, so a new cell is new files plus one
-``workloads`` entry, and no existing file changes.
+of its own under this directory, and so is the architecture family a
+configuration names (``families/<name>.py``), so a new cell is new files
+plus one ``workloads`` entry, and no existing file changes.
 """
 from __future__ import annotations
 
+import importlib
+import importlib.util
 import json
+import sys
 from dataclasses import dataclass, fields
 from pathlib import Path
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Any, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -17,40 +22,14 @@ BENCHMARK = ROOT / "BENCHMARK.json"
 
 
 def data_dir() -> Path:
-    """Where configurations, traffic mixes, checks and metric readers
-    live: this directory (tests point ``HERE`` elsewhere)."""
+    """Where configurations, families, traffic mixes, checks and metric
+    readers live: this directory (tests point ``HERE`` elsewhere)."""
     return HERE
 
 
 class SpecError(ValueError):
-    """A cell, configuration, traffic mix or metric that cannot be
+    """A cell, configuration, family, traffic mix or metric that cannot be
     resolved from the files."""
-
-
-@dataclass(frozen=True)
-class Sizes:
-    """The model as the configuration file states it: what the weights,
-    the work counts and the plain reference are built from."""
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    norm_type: str          # layernorm | rmsnorm
-    norm_eps: float
-    act: str                # silu
-    gated_mlp: bool
-    rope_fraction: float
-    rope_theta: float
-    tie_embeddings: bool
-    dtype: str              # dtype the weights are served in
-
-    @property
-    def rot(self) -> int:
-        """Rotated dims of each head (partial RoPE)."""
-        return int(self.head_dim * self.rope_fraction) // 2 * 2
 
 
 @dataclass(frozen=True)
@@ -83,8 +62,8 @@ class Cell:
 class Config:
     name: str
     arch: str               # the program's registry id
-    reduced: Dict[str, int]
-    sizes: Sizes
+    reduced: Dict[str, Any]
+    sizes: Any              # its family's ``Sizes``
 
 
 def load_benchmark(path: Path = BENCHMARK) -> Dict:
@@ -110,15 +89,50 @@ def cell(bench: Dict, name: str) -> Cell:
                     f"{[w['name'] for w in bench['workloads']]}")
 
 
+def family(name: str) -> ModuleType:
+    """The family file ``families/<name>.py``: the package's own imported
+    as ``chipbench.families.<name>``, another directory's (a test's copy)
+    loaded once under a module name of its own."""
+    path = data_dir() / "families" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no family file families/{name}.py (looked for "
+                        f"{path})")
+    if path == Path(__file__).resolve().parent / "families" / f"{name}.py":
+        return importlib.import_module(f"chipbench.families.{name}")
+    module_name = f"chipbench.families.{name}@{path.parent}"
+    if module_name not in sys.modules:
+        module_spec = importlib.util.spec_from_file_location(module_name,
+                                                             path)
+        module = importlib.util.module_from_spec(module_spec)
+        sys.modules[module_name] = module
+        try:
+            module_spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[module_name]
+            raise
+    return sys.modules[module_name]
+
+
+def family_of(sizes) -> ModuleType:
+    """The family whose ``Sizes`` ``sizes`` is: the weights, work counts
+    and reference that belong to it."""
+    return sys.modules[type(sizes).__module__]
+
+
 def config(name: str) -> Config:
     d = _read_json("configs", name)
+    fam = family(d.get("family", "dense"))
     model = dict(d["model"])
-    reduced = {k: model[k] for k in d.get("reduced", [])}
-    want = {f.name for f in fields(Sizes)}
+    want = {f.name for f in fields(fam.Sizes)}
     if set(model) != want:
         raise SpecError(f"config {name}: model keys {sorted(model)} are not "
                         f"{sorted(want)}")
-    return Config(name, d["arch"], reduced, Sizes(**model))
+    unknown = sorted(set(d.get("reduced", [])) - want)
+    if unknown:
+        raise SpecError(f"config {name}: reduced keys {unknown} are not "
+                        f"model keys")
+    reduced = {k: model[k] for k in d.get("reduced", [])}
+    return Config(name, d["arch"], reduced, fam.Sizes(**model))
 
 
 def traffic(name: str) -> Traffic:
@@ -155,3 +169,11 @@ def metric_unit(bench: Dict, name: str) -> Optional[str]:
             if m["name"] == name:
                 return m["unit"]
     return None
+
+
+def __getattr__(name):
+    """The dense family's ``Sizes``, under the name it had here."""
+    if name == "Sizes":
+        from chipbench.families.dense import Sizes
+        return Sizes
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

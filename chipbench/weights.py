@@ -1,55 +1,42 @@
 """Weights made from the seed, by the benchmark and not by the program.
 
 Every leaf is drawn uniform with a stated mean and standard deviation from
-a key folded from the seed, the leaf's name and, for a per-layer leaf, the
-layer index. So the serve path gets the whole tree from one jitted call on
-the device, in the dtype it is served in, and the plain reference can draw
-any one layer again, alone, after the program's state is freed.
+a key folded from the seed, the leaf's name and, for a leaf stacked over
+layers, the layer index. So the serve path gets the whole tree from one
+jitted call on the device, in the dtype it is served in, and the plain
+reference can draw any one layer again, alone, after the program's state
+is freed.
 
-Scales keep activations near unit size through the depth (matrices with
-standard deviation 1/sqrt(fan-in), embeddings of unit size, norm gains
-near 1 and norm biases near 0), so logits are of unit size and a wrong
-norm gain, bias, rotation or head grouping moves them visibly.
+Which leaves there are is the family's ``recipe`` (``chipbench/families``);
+the family is the one whose ``Sizes`` these functions are handed. Scales
+keep activations near unit size through the depth (matrices with standard
+deviation 1/sqrt(fan-in), embeddings of unit size, norm gains near 1 and
+norm biases near 0), so logits are of unit size and a wrong norm gain,
+bias, rotation or head grouping moves them visibly.
 """
 from __future__ import annotations
 
 import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from chipbench.spec import Sizes
-
-#: leaf path -> (shape without the layer axis, mean, std, served in the
-#: model dtype (True) or in float32 (False)); per-layer leaves live under
-#: "blocks" and gain a leading n_layers axis in the program's tree
-Recipe = Dict[str, Tuple[Tuple[int, ...], float, float, bool]]
+from chipbench.spec import family_of
 
 
-def recipe(s: Sizes) -> Recipe:
-    d, h, g, hd, f = s.d_model, s.n_heads, s.n_kv_heads, s.head_dim, s.d_ff
-    out = {
-        "embed": ((s.vocab, d), 0.0, 1.0, True),
-        "final_norm/scale": ((d,), 1.0, 0.1, False),
-        "blocks/attn/wq": ((d, h, hd), 0.0, d ** -0.5, True),
-        "blocks/attn/wk": ((d, g, hd), 0.0, d ** -0.5, True),
-        "blocks/attn/wv": ((d, g, hd), 0.0, d ** -0.5, True),
-        "blocks/attn/wo": ((h, hd, d), 0.0, (h * hd) ** -0.5, True),
-        "blocks/attn_norm/scale": ((d,), 1.0, 0.1, False),
-        "blocks/mlp_norm/scale": ((d,), 1.0, 0.1, False),
-        "blocks/mlp/wi": ((d, f), 0.0, d ** -0.5, True),
-        "blocks/mlp/wo": ((f, d), 0.0, f ** -0.5, True),
-    }
-    if s.gated_mlp:
-        out["blocks/mlp/wg"] = ((d, f), 0.0, d ** -0.5, True)
-    if s.norm_type == "layernorm":
-        for n in ("final_norm", "blocks/attn_norm", "blocks/mlp_norm"):
-            out[f"{n}/bias"] = ((d,), 0.0, 0.05, False)
-    if not s.tie_embeddings:
-        out["unembed"] = ((d, s.vocab), 0.0, d ** -0.5, True)
-    return out
+class Leaf(NamedTuple):
+    shape: Tuple[int, ...]      # without the layer axis
+    mean: float
+    std: float
+    in_model_dtype: bool        # served in the model dtype, else float32
+    layers: Optional[int]       # stacked over this many layers, or None
+
+
+#: leaf path in the program's tree -> how it is drawn; a stacked leaf
+#: gains a leading axis of ``layers`` in the program's tree
+Recipe = Dict[str, Leaf]
 
 
 def _draw(key, path: str, layer, shape, mean, std, dtype):
@@ -61,7 +48,7 @@ def _draw(key, path: str, layer, shape, mean, std, dtype):
     return w.astype(dtype)
 
 
-def _dtype(s: Sizes, in_model_dtype: bool):
+def _dtype(s, in_model_dtype: bool):
     return jnp.dtype(s.dtype) if in_model_dtype else jnp.dtype(jnp.float32)
 
 
@@ -87,35 +74,39 @@ def _flatten(tree, prefix: str = "") -> Dict:
     return out
 
 
-def check_layout(s: Sizes, abstract) -> None:
+def check_layout(s, abstract) -> None:
     """The program's parameter tree (shapes and dtypes, from
-    ``jax.eval_shape``) has exactly the leaves the recipe draws."""
+    ``jax.eval_shape``) has exactly the leaves the recipe draws, each
+    stacked over the layers the recipe states."""
     want = {}
-    for path, (shape, _, _, model_dt) in recipe(s).items():
-        if path.startswith("blocks/"):
-            shape = (s.n_layers,) + shape
-        want[path] = (tuple(shape), _dtype(s, model_dt))
+    for path, leaf in family_of(s).recipe(s).items():
+        shape = leaf.shape if leaf.layers is None \
+            else (leaf.layers,) + leaf.shape
+        want[path] = (tuple(shape), _dtype(s, leaf.in_model_dtype))
     have = {p: (tuple(a.shape), jnp.dtype(a.dtype))
             for p, a in _flatten(abstract).items()}
-    if have != want:
-        diff = sorted(set(have.items()) ^ set(want.items()))
+    diff = {p: (have.get(p), want.get(p))
+            for p in sorted(set(have) | set(want))
+            if have.get(p) != want.get(p)}
+    if diff:
         raise ValueError(f"the program's parameter tree differs from the "
-                         f"benchmark's recipe: {diff}")
+                         f"benchmark's recipe, leaf: (program, recipe): "
+                         f"{diff}")
 
 
-def make_params(s: Sizes, seed: int, device=None) -> Dict:
+def make_params(s, seed: int, device=None) -> Dict:
     """The whole tree in the program's layout, in one jitted call."""
-    rec = recipe(s)
+    rec = family_of(s).recipe(s)
 
     def build(key):
         flat = {}
-        for path, (shape, mean, std, model_dt) in rec.items():
+        for path, (shape, mean, std, model_dt, layers) in rec.items():
             dt = _dtype(s, model_dt)
-            if path.startswith("blocks/"):
+            if layers is not None:
                 flat[path] = jax.vmap(
                     lambda l, path=path, shape=shape, mean=mean, std=std,
                     dt=dt: _draw(key, path, l, shape, mean, std, dt))(
-                        jnp.arange(s.n_layers))
+                        jnp.arange(layers))
             else:
                 flat[path] = _draw(key, path, None, shape, mean, std, dt)
         return _nest(flat)
@@ -127,15 +118,16 @@ def make_params(s: Sizes, seed: int, device=None) -> Dict:
     return jax.jit(build, out_shardings=out)(jax.random.PRNGKey(seed))
 
 
-def _layer_fn(s: Sizes):
-    rec = {p[len("blocks/"):]: r for p, r in recipe(s).items()
-           if p.startswith("blocks/")}
+def _layer_fn(s, rec: Recipe, stack: str):
+    prefix = stack + "/"
+    leaves = {p[len(prefix):]: (p, leaf) for p, leaf in rec.items()
+              if p.startswith(prefix) and leaf.layers is not None}
 
     @jax.jit
     def layer(key, l):
         flat = {}
-        for name, (shape, mean, std, model_dt) in rec.items():
-            flat[name] = _draw(key, "blocks/" + name, l, shape, mean, std,
+        for name, (path, (shape, mean, std, model_dt, _)) in leaves.items():
+            flat[name] = _draw(key, path, l, shape, mean, std,
                                _dtype(s, model_dt)).astype(jnp.float32)
         return _nest(flat)
     return layer
@@ -145,18 +137,23 @@ class Redraw:
     """Draws the served values again, in float32, one layer at a time:
     what the plain reference computes with."""
 
-    def __init__(self, s: Sizes, seed: int):
+    def __init__(self, s, seed: int):
         self.s = s
         self.key = jax.random.PRNGKey(seed)
-        self._layer = _layer_fn(s)
+        self.recipe = family_of(s).recipe(s)
+        self._layers = {}
 
-    def layer(self, l: int) -> Dict:
-        return self._layer(self.key, l)
+    def layer(self, l: int, stack: str = "blocks") -> Dict:
+        """Layer ``l`` of the stacked leaves under ``stack/`` (a dense
+        model's one stack is ``blocks``), with that prefix taken off."""
+        if stack not in self._layers:
+            self._layers[stack] = _layer_fn(self.s, self.recipe, stack)
+        return self._layers[stack](self.key, l)
 
     def top(self) -> Dict:
         out = {}
-        for path, (shape, mean, std, model_dt) in recipe(self.s).items():
-            if not path.startswith("blocks/"):
+        for path, (shape, mean, std, model_dt, layers) in self.recipe.items():
+            if layers is None:
                 out[path] = _draw(self.key, path, None, shape, mean, std,
                                   _dtype(self.s, model_dt)) \
                     .astype(jnp.float32)
