@@ -11,11 +11,14 @@ Families
 ``audio``   dense backbone over EnCodec-token streams (frontend is a stub)
 ``moe``     dense attention + mixture-of-experts FFN (top-k routing, EP-sharded)
 ``ssm``     xLSTM: alternating mLSTM / sLSTM blocks
-``hybrid``  Zamba2-style: Mamba-2 backbone with a shared attention block
+``hybrid``  Mamba-2 with attention: Zamba2-style (one shared attention
+            block every k Mamba blocks) or a per-layer pattern of Mamba-2
+            and attention mixers, each followed by its own MLP (granite-4.0-h)
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -60,8 +63,15 @@ class XLSTMConfig:
 
 @dataclass(frozen=True)
 class HybridConfig:
-    """Zamba2-style hybrid: Mamba-2 backbone + shared attention block."""
+    """Mamba-2 backbone with attention, in one of two layouts.
+
+    ``layer_types`` empty: Zamba2-style, one parameter-shared attention(+MLP)
+    block applied every ``attn_every`` Mamba-2 blocks. ``layer_types`` given
+    (one of "mamba" / "attention" per layer): each layer is that mixer, with
+    weights of its own, followed by an MLP of its own (granite-4.0-h).
+    """
     attn_every: int = 6          # apply the shared attention block every k SSM blocks
+    layer_types: Tuple[str, ...] = ()
 
 
 @dataclass(frozen=True)
@@ -105,11 +115,25 @@ class LMConfig:
     # (EXPERIMENTS.md §Perf, decode iteration 1)
     decode_unroll: bool = False
     max_seq_len: int = 32_768
+    # muP-style multipliers (granite): token embeddings are scaled by
+    # ``embedding_multiplier``, each residual branch by
+    # ``residual_multiplier`` (hybrid layer pattern), attention scores by
+    # ``attention_multiplier`` (0: 1/sqrt(head_dim)), and logits divided
+    # by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # ---- derived -------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def attn_scale(self) -> float:
+        return self.attention_multiplier or \
+            1.0 / math.sqrt(self.resolved_head_dim)
 
     @property
     def activation_dtype(self):
@@ -156,9 +180,14 @@ class LMConfig:
             in_proj = d * (2 * d_inner + 2 * s.n_groups * s.d_state + nheads)
             blk = in_proj + s.d_conv * (d_inner + 2 * s.n_groups * s.d_state) \
                 + d_inner * d + 2 * d
-            shared_attn = d * self.n_heads * hd * 2 \
-                + 2 * d * self.n_kv_heads * hd + d * self.d_ff * 3
-            return n_emb + self.n_layers * blk + shared_attn
+            attn = d * self.n_heads * hd * 2 + 2 * d * self.n_kv_heads * hd
+            mlp = d * self.d_ff * 3
+            types = self.hybrid.layer_types if self.hybrid else ()
+            if types:
+                n_attn = types.count("attention")
+                return n_emb + (len(types) - n_attn) * blk \
+                    + n_attn * (attn + d) + len(types) * (mlp + d) + d
+            return n_emb + self.n_layers * blk + attn + mlp
         total = n_emb + self.n_layers * per_layer + d
         return total
 
@@ -226,6 +255,12 @@ def reduced(cfg: LMConfig) -> LMConfig:
             cfg.ssm, d_state=8, head_dim=16, expand=2, chunk_size=16)
     if cfg.xlstm:
         kw["xlstm"] = dataclasses.replace(cfg.xlstm, chunk_size=16)
-    if cfg.hybrid:
+    if cfg.hybrid and cfg.hybrid.layer_types:
+        # two periods of a short pattern that keeps both mixer kinds
+        kw["n_layers"] = 8
+        kw["hybrid"] = dataclasses.replace(
+            cfg.hybrid, layer_types=("mamba", "mamba", "attention",
+                                     "mamba") * 2)
+    elif cfg.hybrid:
         kw["hybrid"] = dataclasses.replace(cfg.hybrid, attn_every=2)
     return cfg.replace(**kw)

@@ -8,11 +8,12 @@ from repro.configs.base import LMConfig, ShapeSuite, SHAPES, SHAPES_BY_NAME, \
 
 from repro.configs import yi_6b, deepseek_7b, phi3_medium_14b, stablelm_1_6b, \
     phi3_vision_4_2b, musicgen_large, xlstm_350m, phi35_moe_42b, \
-    granite_moe_1b, zamba2_1_2b
+    granite_moe_1b, zamba2_1_2b, granite_4_0_h_micro
 
 _MODULES = (
     yi_6b, deepseek_7b, phi3_medium_14b, stablelm_1_6b, phi3_vision_4_2b,
     musicgen_large, xlstm_350m, phi35_moe_42b, granite_moe_1b, zamba2_1_2b,
+    granite_4_0_h_micro,
 )
 
 ARCHS: Dict[str, LMConfig] = {m.CONFIG.arch_id: m.CONFIG for m in _MODULES}

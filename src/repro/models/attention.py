@@ -80,12 +80,14 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       q_chunk: int = 512, kv_chunk: int = 1024,
                       kv_len: Optional[jax.Array] = None,
                       q_offset: int = 0,
-                      block_skip: bool = False) -> jax.Array:
+                      block_skip: bool = False,
+                      scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention over (q, kv) chunks.
 
     q: (b, sq, g, m, hd); k, v: (b, skv, g, hd). Returns (b, sq, g, m, hd).
     ``kv_len`` (scalar or (b,)) masks cache positions >= kv_len.
     ``q_offset``: absolute position of q[0] (for decode-with-history).
+    ``scale`` multiplies the scores (default 1/sqrt(hd)).
     """
     b, sq, g, m, hd = q.shape
     skv = k.shape[1]
@@ -103,7 +105,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if kv_len is None:
             kv_len = jnp.full((b,), skv0, jnp.int32)
     nq, nk = sq // qc, skv // kc
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qs = q.reshape(b, nq, qc, g, m, hd).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(b, nk, kc, g, hd).transpose(1, 0, 2, 3, 4)
     vs = v.reshape(b, nk, kc, g, hd).transpose(1, 0, 2, 3, 4)
@@ -176,11 +179,13 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     kv_len: jax.Array) -> jax.Array:
+                     kv_len: jax.Array,
+                     scale: Optional[float] = None) -> jax.Array:
     """Single-position attention against a (padded) KV cache whose kv heads
     share one minor axis.
 
-    q: (b, 1, g, m, hd); caches: (b, S, g * hd); kv_len: scalar or (b,).
+    q: (b, 1, g, m, hd); caches: (b, S, g * hd); kv_len: scalar or (b,);
+    ``scale`` multiplies the scores (default 1/sqrt(hd)).
     Both products contract the packed axis or the sequence, each as one
     matrix product per row, so the caches are read in the layout they are
     stored in: q is spread block-diagonally over the g groups, and each
@@ -198,15 +203,16 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     q_axes = ("cache_batch", None, kv, None, None)
     cache_axes = ("cache_batch", None, kv)
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len), (b,))
-    return per_shard(_decode_attention,
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return per_shard(partial(_decode_attention, scale=scale),
                      (q_axes, cache_axes, cache_axes, ("cache_batch",)),
                      q_axes)(q, k_cache, v_cache, kv_len)
 
 
-def _decode_attention(q, k_cache, v_cache, kv_len):
+def _decode_attention(q, k_cache, v_cache, kv_len, scale):
     b, _, g, m, hd = q.shape
     S = k_cache.shape[1]
-    scale = 1.0 / math.sqrt(hd)
     own = jnp.eye(g, dtype=bool)
     q_bd = jnp.where(own[:, None, :, None], q[:, 0, :, :, None], 0)
     q_bd = q_bd.reshape(b, g * m, g * hd)

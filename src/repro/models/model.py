@@ -197,7 +197,9 @@ def forward(cfg: LMConfig, params, tokens, prefix_emb=None, remat=False,
 
 
 def unembed_weight(cfg: LMConfig, params):
-    return params["embed"].T if cfg.tie_embeddings else params["unembed"]
+    """The output head: hidden @ unembed_weight gives the logits."""
+    w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+    return w / cfg.logits_scaling if cfg.logits_scaling != 1.0 else w
 
 
 def prefill(cfg: LMConfig, params, tokens, prefix_emb=None, max_len=None):

@@ -70,11 +70,17 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, chunk: int,
              init_state: Optional[jax.Array] = None):
-    """Chunked state-space-dual scan.
+    """Chunked state-space-dual scan, one chunk after another.
 
     x: (b, s, nh, hd); dt: (b, s, nh); A: (nh,) (negative);
     B, C: (b, s, g, n) with nh % g == 0.
     Returns (y (b, s, nh, hd), final_state (b, nh, hd, n)).
+
+    Within a chunk, with cum the running sum of dt·A from the chunk's
+    start: y_i = sum_{j<=i} exp(cum_i - cum_j) dt_j (C_i·B_j) x_j (the
+    quadratic form, C·B once per group) plus exp(cum_i) C_i·S, where S is
+    the state entering the chunk. The loop carries S in float32; one
+    chunk's (Q, Q) tiles are all that is held at a time.
     """
     b, s, nh, hd = x.shape
     g, n = B.shape[2], B.shape[3]
@@ -91,50 +97,41 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
         s += pad
     nc = s // Q
-    Bh = jnp.repeat(B, rep, axis=2)            # (b, s, nh, n) broadcasted heads
-    Ch = jnp.repeat(C, rep, axis=2)
+    # chunk-major, heads split by group: (nc, b, Q, g, rep, ...)
+    chunks = lambda a: jnp.moveaxis(a.reshape(b, nc, Q, *a.shape[2:]), 1, 0)
+    xs = chunks(x.reshape(b, s, g, rep, hd))
+    dts = chunks(dt.reshape(b, s, g, rep))
+    Bs, Cs = chunks(B), chunks(C)
+    Ah = A.reshape(g, rep)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
 
-    xc = x.reshape(b, nc, Q, nh, hd)
-    dtc = dt.reshape(b, nc, Q, nh)
-    Bc = Bh.reshape(b, nc, Q, nh, n)
-    Cc = Ch.reshape(b, nc, Q, nh, n)
+    def body(S, inp):                    # S: (b, g, rep, hd, n) float32
+        xq, dtq, Bq, Cq = inp
+        cum = jnp.cumsum(dtq * Ah, axis=1)                    # (b,Q,g,rep)
+        cum_t = jnp.moveaxis(cum, 1, -1)                      # (b,g,rep,Q)
+        seg = cum_t[..., :, None] - cum_t[..., None, :]       # [i, j]
+        decay = jnp.exp(jnp.where(causal, seg, NEG_INF))
+        scores = jnp.einsum("bign,bjgn->bgij", Cq, Bq,
+                            preferred_element_type=jnp.float32)
+        wgt = decay * scores[:, :, None] \
+            * jnp.moveaxis(dtq, 1, -1)[..., None, :]          # (b,g,rep,i,j)
+        y = jnp.einsum("bgrij,bjgrp->bigrp", wgt.astype(x.dtype), xq,
+                       preferred_element_type=jnp.float32)
+        y = y + jnp.einsum("bign,bgrpn->bigrp", Cq, S.astype(x.dtype),
+                           preferred_element_type=jnp.float32) \
+            * jnp.exp(cum)[..., None]
+        # the state leaving the chunk
+        w_end = jnp.exp(cum[:, -1:] - cum) * dtq                # (b,Q,g,rep)
+        S = S * jnp.exp(cum[:, -1])[..., None, None] + jnp.einsum(
+            "bjgrp,bjgn->bgrpn", (xq * w_end[..., None]).astype(x.dtype),
+            Bq, preferred_element_type=jnp.float32)
+        return S, y
 
-    dA = dtc * A                                # (b, nc, Q, nh) log-decay
-    cum = jnp.cumsum(dA, axis=2)                # inclusive cumsum
-    # intra-chunk: y[i] = sum_{j<=i} exp(cum_i - cum_j) dt_j (C_i·B_j) x_j
-    Lmat = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # (b,nc,Q,Q,nh)
-    tri = jnp.tril(jnp.ones((Q, Q), bool))
-    Lmat = jnp.where(tri[None, None, :, :, None], Lmat, NEG_INF)
-    scores = jnp.einsum("bcqhn,bckhn->bcqkh", Cc, Bc,
-                        preferred_element_type=jnp.float32)
-    wgt = jnp.exp(Lmat) * scores * dtc[:, :, None, :, :]
-    y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", wgt.astype(x.dtype), xc,
-                         preferred_element_type=jnp.float32)
-
-    # chunk end-states: S_c = sum_j exp(cum_last - cum_j) dt_j B_j ⊗ x_j
-    decay_end = jnp.exp(cum[:, :, -1:, :] - cum)              # (b,nc,Q,nh)
-    sw = (decay_end * dtc).astype(x.dtype)
-    states = jnp.einsum("bckhn,bckhp->bchnp", Bc * sw[..., None], xc,
-                        preferred_element_type=jnp.float32)   # (b,nc,nh,n,hd)
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                   # (b, nc, nh)
-
-    def carry_fn(S, inp):
-        st, dec = inp                    # (b, nh, n, hd), (b, nh)
-        S_new = S * dec[..., None, None] + st
-        return S_new, S                  # emit state *entering* each chunk
-
-    S0 = jnp.zeros((b, nh, n, hd), jnp.float32) if init_state is None \
-        else init_state.transpose(0, 1, 3, 2)  # (b,nh,hd,n)->(b,nh,n,hd)
-    Sf, S_in = jax.lax.scan(carry_fn, S0,
-                            (states.transpose(1, 0, 2, 3, 4),
-                             chunk_decay.transpose(1, 0, 2)))
-    S_in = S_in.transpose(1, 0, 2, 3, 4)                      # (b,nc,nh,n,hd)
-
-    y_inter = jnp.einsum("bcqhn,bchnp->bcqhp",
-                         (Cc * jnp.exp(cum)[..., None]).astype(x.dtype), S_in,
-                         preferred_element_type=jnp.float32)
-    y = (y_intra + y_inter).reshape(b, s, nh, hd)[:, :s0]
-    return y.astype(x.dtype), Sf.transpose(0, 1, 3, 2)        # (b,nh,hd,n)
+    S0 = jnp.zeros((b, g, rep, hd, n), jnp.float32) if init_state is None \
+        else init_state.astype(jnp.float32).reshape(b, g, rep, hd, n)
+    Sf, ys = jax.lax.scan(body, S0, (xs, dts, Bs, Cs))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, s, nh, hd)[:, :s0]
+    return y.astype(x.dtype), Sf.reshape(b, nh, hd, n)
 
 
 def mamba2_split(cfg: LMConfig, zxbcdt: jax.Array):
@@ -146,14 +143,15 @@ def mamba2_split(cfg: LMConfig, zxbcdt: jax.Array):
     return z, xin, B, C, dt
 
 
-def mamba2_block_fwd(cfg: LMConfig, p: Dict, x: jax.Array,
-                     init_state: Optional[jax.Array] = None,
-                     return_state: bool = False):
-    """Full-sequence Mamba-2 block. x: (b, s, d)."""
+def mamba2_mixer(cfg: LMConfig, p: Dict, h: jax.Array,
+                 init_state: Optional[jax.Array] = None,
+                 return_state: bool = False):
+    """The Mamba-2 mixer over a normed sequence, h: (b, s, d) -> (b, s, d),
+    without the block's residual. With ``return_state``, also the state
+    and the conv window that decoding continues from."""
     s_cfg = cfg.ssm
-    b, s, d = x.shape
+    b, s, d = h.shape
     di, nh, conv_dim = mamba2_dims(cfg)
-    h = rmsnorm(x, p["pre_norm"], cfg.norm_eps)
     zxbcdt = jnp.einsum("bsd,dk->bsk", h, p["in_proj"])
     z, xin, B, C, dtr = mamba2_split(cfg, zxbcdt)
     xBC_raw = jnp.concatenate([xin, B, C], axis=-1)
@@ -170,23 +168,36 @@ def mamba2_block_fwd(cfg: LMConfig, p: Dict, x: jax.Array,
     y = y + (p["D"][:, None] * xh.astype(jnp.float32)).astype(y.dtype)
     y = y.reshape(b, s, di)
     y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-    out = x + jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
-    out = lshard(out, "act_batch", "act_res_seq", "act_embed")
+    out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
     if return_state:
-        assert s >= s_cfg.d_conv - 1, "prefill shorter than conv window"
-        conv_tail = xBC_raw[:, s - (s_cfg.d_conv - 1):, :]
-        return out, (Sf, conv_tail)
+        if s < s_cfg.d_conv - 1:
+            raise ValueError(f"a prompt of {s} positions is shorter than "
+                             f"the conv window ({s_cfg.d_conv - 1})")
+        return out, (Sf, xBC_raw[:, s - (s_cfg.d_conv - 1):, :])
     return out
 
 
-def mamba2_decode_step(cfg: LMConfig, p: Dict, x: jax.Array,
-                       state: jax.Array, conv_buf: jax.Array):
-    """One-token Mamba-2 step. x: (b, 1, d); state: (b, nh, hd, n);
-    conv_buf: (b, d_conv-1, conv_dim). Returns (out, state', conv_buf')."""
-    s_cfg = cfg.ssm
-    b = x.shape[0]
-    di, nh, conv_dim = mamba2_dims(cfg)
+def mamba2_block_fwd(cfg: LMConfig, p: Dict, x: jax.Array,
+                     init_state: Optional[jax.Array] = None,
+                     return_state: bool = False):
+    """Full-sequence Mamba-2 block: x + mixer(RMSNorm(x)). x: (b, s, d)."""
     h = rmsnorm(x, p["pre_norm"], cfg.norm_eps)
+    y = mamba2_mixer(cfg, p, h, init_state, return_state)
+    state = None
+    if return_state:
+        y, state = y
+    out = lshard(x + y, "act_batch", "act_res_seq", "act_embed")
+    return (out, state) if return_state else out
+
+
+def mamba2_mixer_step(cfg: LMConfig, p: Dict, h: jax.Array,
+                      state: jax.Array, conv_buf: jax.Array):
+    """One position of the Mamba-2 mixer. h: (b, 1, d), normed; state:
+    (b, nh, hd, n) float32; conv_buf: (b, d_conv-1, conv_dim). Returns
+    (out (b, 1, d), state', conv_buf'), without the block's residual."""
+    s_cfg = cfg.ssm
+    b = h.shape[0]
+    di, nh, conv_dim = mamba2_dims(cfg)
     zxbcdt = jnp.einsum("bsd,dk->bsk", h, p["in_proj"])
     z, xin, B, C, dtr = mamba2_split(cfg, zxbcdt)
     xBC_new = jnp.concatenate([xin, B, C], axis=-1)           # (b, 1, conv_dim)
@@ -206,10 +217,18 @@ def mamba2_decode_step(cfg: LMConfig, p: Dict, x: jax.Array,
         "bhp,bhn,bh->bhpn", xh.astype(jnp.float32), Bh.astype(jnp.float32), dt)
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch.astype(jnp.float32))
     y = y + p["D"][:, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, 1, di).astype(x.dtype)
+    y = y.reshape(b, 1, di).astype(h.dtype)
     y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-    out = x + jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
-    return out, state, win[:, 1:, :]
+    return jnp.einsum("bsk,kd->bsd", y, p["out_proj"]), state, win[:, 1:, :]
+
+
+def mamba2_decode_step(cfg: LMConfig, p: Dict, x: jax.Array,
+                       state: jax.Array, conv_buf: jax.Array):
+    """One-token Mamba-2 block: x + mixer step. x: (b, 1, d). Returns
+    (out, state', conv_buf')."""
+    h = rmsnorm(x, p["pre_norm"], cfg.norm_eps)
+    y, state, conv_buf = mamba2_mixer_step(cfg, p, h, state, conv_buf)
+    return x + y, state, conv_buf
 
 
 # ===========================================================================

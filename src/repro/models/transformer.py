@@ -93,6 +93,29 @@ def _qkv(cfg: LMConfig, p: Dict, h: jax.Array, positions: jax.Array):
     return q, k, v
 
 
+def attend(cfg: LMConfig, p: Dict, h: jax.Array, positions: jax.Array):
+    """Causal self-attention over whole prompts. h: (b, s, d), normed.
+    Returns the output projected back to (b, s, d), and the keys and
+    values, (b, s, n_kv_heads, head_dim)."""
+    q, k, v = _qkv(cfg, p, h, positions)
+    qg = group_query_heads(q, cfg.n_kv_heads)
+    o = chunked_attention(qg, k, v, causal=True, q_chunk=cfg.q_chunk,
+                          kv_chunk=cfg.kv_chunk,
+                          block_skip=cfg.causal_block_skip,
+                          scale=cfg.attn_scale)
+    return jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), p["wo"]), k, v
+
+
+def pack_kv(cfg: LMConfig, kv: jax.Array, max_len: int) -> jax.Array:
+    """One layer's keys or values, (b, s, n_kv_heads, head_dim), in the
+    cache's layout: (b, max_len, n_kv_heads * head_dim), zero past s."""
+    b, s = kv.shape[:2]
+    kv = kv.reshape(b, s, -1)
+    if max_len > s:
+        kv = jnp.pad(kv, [(0, 0), (0, max_len - s), (0, 0)])
+    return lshard(kv, "cache_batch", "cache_seq", _kv_axis(cfg))
+
+
 def attn_block_fwd(cfg: LMConfig, p: Dict, x: jax.Array,
                    positions: jax.Array) -> jax.Array:
     h = norm(x, p["attn_norm"], cfg.norm_type, cfg.norm_eps)
@@ -110,7 +133,8 @@ def attn_block_fwd(cfg: LMConfig, p: Dict, x: jax.Array,
     else:
         o = chunked_attention(qg, k, v, causal=True, q_chunk=cfg.q_chunk,
                               kv_chunk=cfg.kv_chunk,
-                              block_skip=cfg.causal_block_skip)
+                              block_skip=cfg.causal_block_skip,
+                              scale=cfg.attn_scale)
     o = ungroup_heads(o)
     o = jnp.einsum("bshk,hkd->bsd", o, p["attn"]["wo"])
     return x + lshard(o, "act_batch", "act_res_seq", "act_embed")
@@ -136,9 +160,17 @@ def block_fwd(cfg: LMConfig, p: Dict, x: jax.Array, positions: jax.Array):
 # embedding / logits
 # ---------------------------------------------------------------------------
 
+def embed_rows(cfg: LMConfig, params: Dict, tokens: jax.Array) -> jax.Array:
+    """The tokens' embedding rows, times the embedding multiplier."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def embed_tokens(cfg: LMConfig, params: Dict, tokens: jax.Array,
                  prefix_emb: Optional[jax.Array], pos0: int = 0):
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = embed_rows(cfg, params, tokens)
     if prefix_emb is not None:
         x = jnp.concatenate([prefix_emb.astype(x.dtype), x], axis=1)
     s = x.shape[1]
@@ -153,6 +185,8 @@ def embed_tokens(cfg: LMConfig, params: Dict, tokens: jax.Array,
 def logits_fwd(cfg: LMConfig, params: Dict, x: jax.Array) -> jax.Array:
     w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = jnp.einsum("bsd,dv->bsv", x, w)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return lshard(logits, "act_batch", "act_seq", "act_vocab")
 
 
@@ -193,21 +227,10 @@ def prefill(cfg: LMConfig, params: Dict, tokens: jax.Array,
     def body(x, bp):
         h = norm(x, bp["attn_norm"], cfg.norm_type, cfg.norm_eps)
         h = lshard(h, "act_batch", "act_seq", "act_embed")
-        q, k, v = _qkv(cfg, bp["attn"], h, positions)
-        qg = group_query_heads(q, cfg.n_kv_heads)
-        o = chunked_attention(qg, k, v, causal=True, q_chunk=cfg.q_chunk,
-                              kv_chunk=cfg.kv_chunk,
-                              block_skip=cfg.causal_block_skip)
-        o = jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), bp["attn"]["wo"])
+        o, k, v = attend(cfg, bp["attn"], h, positions)
         x = x + lshard(o, "act_batch", "act_res_seq", "act_embed")
         x, _ = ffn_block_fwd(cfg, bp, x)
-        k, v = k.reshape(b, s, -1), v.reshape(b, s, -1)
-        if S > s:
-            pad = [(0, 0), (0, S - s), (0, 0)]
-            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        k = lshard(k, "cache_batch", "cache_seq", _kv_axis(cfg))
-        v = lshard(v, "cache_batch", "cache_seq", _kv_axis(cfg))
-        return x, (k, v)
+        return x, (pack_kv(cfg, k, S), pack_kv(cfg, v, S))
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
     x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
@@ -256,41 +279,52 @@ def write_kv(cfg: LMConfig, cache: jax.Array, new: jax.Array, i,
                            ("cache_batch",)), ax)(cache, new, i, pos)
 
 
+def layer_kv(cfg: LMConfig, cache: jax.Array, i) -> jax.Array:
+    """Layer ``i`` of the stacked cache, (b, S, n_kv_heads * head_dim)."""
+    c = jax.lax.dynamic_index_in_dim(cache, i, 0, keepdims=False)
+    return lshard(c, "cache_batch", "cache_seq", _kv_axis(cfg))
+
+
+def attend_step(cfg: LMConfig, p: Dict, h: jax.Array, ck: jax.Array,
+                cv: jax.Array, i, pos: jax.Array):
+    """One new position of attention layer ``i``. h: (b, 1, d), normed;
+    ck, cv: the stacked caches; pos: (b,) each row's position. Writes the
+    new key and value at ``pos``, attends over positions 0..pos, and
+    returns (output (b, 1, d), ck, cv)."""
+    b = h.shape[0]
+    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
+    k = jnp.einsum("bsd,dgk->bsgk", h, p["wk"])
+    v = jnp.einsum("bsd,dgk->bsgk", h, p["wv"])
+    if cfg.pos_emb == "rope":
+        inv, rot = rope_freqs(cfg.resolved_head_dim, cfg.rope_fraction,
+                              cfg.rope_theta)
+        q = apply_rope(q, pos[:, None], inv, rot)
+        k = apply_rope(k, pos[:, None], inv, rot)
+    ck = write_kv(cfg, ck, k.reshape(b, -1), i, pos)
+    cv = write_kv(cfg, cv, v.reshape(b, -1), i, pos)
+    qg = group_query_heads(q, cfg.n_kv_heads)
+    o = decode_attention(qg, layer_kv(cfg, ck, i), layer_kv(cfg, cv, i),
+                         pos + 1, scale=cfg.attn_scale)
+    return jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), p["wo"]), ck, cv
+
+
 def decode_step(cfg: LMConfig, params: Dict, cache: Dict, tokens: jax.Array):
     """One decode step. tokens: (b, 1). Returns (logits, new_cache).
 
     The stacked cache rides in the layer loop's carry and each layer writes
     only its row's new position, so a donated cache is updated in place.
     """
-    b = tokens.shape[0]
     pos = cache["pos"]                                   # (b,)
-    x = jnp.take(params["embed"], tokens, axis=0)        # (b, 1, d)
+    x = embed_rows(cfg, params, tokens)                  # (b, 1, d)
     if cfg.pos_emb == "learned":
         pe = jnp.take(params["pos_emb"], pos, axis=0)[:, None, :]
         x = x + pe
     x = lshard(x, "act_batch", "act_res_seq", "act_embed")
-    positions = pos[:, None]
-    inv, rot = rope_freqs(cfg.resolved_head_dim, cfg.rope_fraction,
-                          cfg.rope_theta)
-
-    def layer_kv(c, i):
-        c = jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False)
-        return lshard(c, "cache_batch", "cache_seq", _kv_axis(cfg))
 
     def body(carry, bp, i):
         x, ck, cv = carry
         h = norm(x, bp["attn_norm"], cfg.norm_type, cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"])
-        k = jnp.einsum("bsd,dgk->bsgk", h, bp["attn"]["wk"])
-        v = jnp.einsum("bsd,dgk->bsgk", h, bp["attn"]["wv"])
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, positions, inv, rot)
-            k = apply_rope(k, positions, inv, rot)
-        ck = write_kv(cfg, ck, k.reshape(b, -1), i, pos)
-        cv = write_kv(cfg, cv, v.reshape(b, -1), i, pos)
-        qg = group_query_heads(q, cfg.n_kv_heads)
-        o = decode_attention(qg, layer_kv(ck, i), layer_kv(cv, i), pos + 1)
-        o = jnp.einsum("bshk,hkd->bsd", ungroup_heads(o), bp["attn"]["wo"])
+        o, ck, cv = attend_step(cfg, bp["attn"], h, ck, cv, i, pos)
         x = x + lshard(o, "act_batch", "act_res_seq", "act_embed")
         x, _ = ffn_block_fwd(cfg, bp, x)
         return x, ck, cv

@@ -13,6 +13,7 @@ import os
 import tempfile
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Callable, Dict, List, Optional
@@ -232,6 +233,24 @@ class HeteroServeEngine:
                                              kind="real")
             padded_rows = tel.registry.counter("exec.rows", group=key,
                                                kind="padded")
+            # device bytes of the decode caches that this executor's
+            # chunks hold, observed as each chunk's prefill and decode
+            # calls are issued: a chunk holds its cache from its prefill
+            # until its outputs are fetched (or dropped unfetched)
+            cache_bytes = tel.registry.histogram("exec.cache_bytes_in_flight",
+                                                 group=key)
+            held = {"bytes": 0}
+            held_lock = threading.Lock()
+
+            def hold(outputs, nbytes: int) -> None:
+                with held_lock:
+                    held["bytes"] += nbytes
+                    cache_bytes.observe(held["bytes"])
+                weakref.finalize(outputs, release, nbytes)
+
+            def release(nbytes: int) -> None:
+                with held_lock:
+                    held["bytes"] -= nbytes
 
         def step(batch):
             if g.fail_after_chunks is not None:
@@ -248,6 +267,8 @@ class HeteroServeEngine:
                                            batch.get("prefix_emb"))
                 tok = jnp.argmax(logits[:, -1], -1)[:, None] \
                     .astype(jnp.int32)
+            # the cache's size from its shapes: no wait for the device
+            nbytes = sum(a.nbytes for a in jax.tree.leaves(cache))
             toks = [tok]
             for _ in range(self.decode_tokens - 1):
                 with telemetry_mod.annotate(tel, "exec.decode", group=key,
@@ -256,7 +277,10 @@ class HeteroServeEngine:
                     tok = jnp.argmax(logits[:, -1], -1)[:, None] \
                         .astype(jnp.int32)
                 toks.append(tok)
-            return jnp.concatenate(toks, axis=1), batch["rows"]
+            gen = jnp.concatenate(toks, axis=1)
+            if tel is not None:
+                hold(gen, nbytes)
+            return gen, batch["rows"]
 
         def fetch(outs):
             gen, rows = outs

@@ -260,6 +260,51 @@ def test_engine_decode_donates_cache():
                                   np.asarray(kept["k"]))
 
 
+def test_engine_decode_donates_hybrid_cache():
+    """The hybrid layer pattern's decode program takes its whole cache
+    (SSM state, conv windows, keys and values) by donation, and serves
+    the tokens an undonated call of the same step serves."""
+    from repro.core.types import DeviceKind
+    from repro.serve.engine import HeteroServeEngine
+    from repro.train.trainer import GroupDef
+    cfg = get_reduced_config("granite-4.0-h-micro")
+    eng = HeteroServeEngine(cfg, [GroupDef("accel", DeviceKind.ACCEL)],
+                            prompt_len=11, decode_tokens=4)
+    prefill_fn, decode_fn = eng._fns_for(2)
+    step = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
+    tokens = np.stack([eng._prompt(i) for i in range(2)])
+    logits, cache = prefill_fn(eng.params, tokens, None)
+    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+    kept = jax.tree.map(jnp.copy, cache)
+    for _ in range(eng.decode_tokens - 1):
+        given = cache
+        logits, cache = decode_fn(eng.params, cache, tok)
+        assert all(a.is_deleted() for a in jax.tree.leaves(given))
+        _, kept = step(eng.params, kept, tok)
+        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+    for name in ("ssm_state", "conv", "k", "v", "pos"):
+        np.testing.assert_array_equal(np.asarray(cache[name]),
+                                      np.asarray(kept[name]))
+
+
+@pytest.mark.parametrize("arch", ["zamba2-1.2b", "granite-4.0-h-micro"])
+def test_hybrid_keys_and_values_share_the_transformer_layout(arch):
+    """Both hybrid layouts keep attention keys and values as the
+    transformer does: (layers, batch, positions, kv heads * head_dim)."""
+    cfg = get_reduced_config(arch)
+    kv = cfg.n_kv_heads * cfg.resolved_head_dim
+    params = M.init_params(cfg, KEY)
+    _, cache = M.prefill(cfg, params, jnp.zeros((3, 9), jnp.int32),
+                         max_len=16)
+    abstract = M.init_cache(cfg, 3, 16, abstract=True)
+    keys = [k for k in cache if k in ("k", "v", "ak", "av")]
+    assert len(keys) == 2
+    for k in keys:
+        assert cache[k].ndim == 4 and cache[k].shape[1:] == (3, 16, kv)
+        assert abstract[k].shape == cache[k].shape
+        assert len(M.cache_axes(cfg)[k]) == 4
+
+
 def test_ssd_scan_matches_recurrence():
     from repro.models.ssm import ssd_scan
     from repro.kernels.ref import ssd_scan_ref
@@ -287,7 +332,8 @@ def test_ssd_scan_matches_recurrence():
                                   "stablelm-1.6b", "musicgen-large",
                                   "phi3.5-moe-42b-a6.6b",
                                   "granite-moe-1b-a400m", "xlstm-350m",
-                                  "zamba2-1.2b", "phi-3-vision-4.2b"])
+                                  "zamba2-1.2b", "phi-3-vision-4.2b",
+                                  "granite-4.0-h-micro"])
 def test_prefill_decode_matches_forward(arch):
     cfg = get_reduced_config(arch).replace(dtype="float32")
     params = M.init_params(cfg, KEY)

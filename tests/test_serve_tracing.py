@@ -52,6 +52,38 @@ def _spans(tel, lo_us=0.0):
             if e.get("ph") == "X" and e["ts"] >= lo_us]
 
 
+def test_cache_in_flight_is_observed_per_chunk_and_released(engine):
+    """Each chunk observes the decode cache its group holds: its own
+    and, at most, that of the one chunk before it still in flight
+    (accel's pipeline depth is 2). Once a serve call has fetched every
+    chunk, the next call's chunks observe their own caches alone."""
+    import jax
+
+    from repro.models import model as M
+    tel = engine.telemetry
+    name = "exec.cache_bytes_in_flight"
+    # accel's chunks of 3 and cpu0's of up to 4 pad to 4 rows
+    one = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        M.init_cache(engine.cfg, 4, engine.max_len, abstract=True)))
+    before = tel.snapshot()
+    rep = engine.serve_jobs([Job(items=3) for _ in range(4)], batch_jobs=4,
+                            timeout_s=120.0)
+    after = tel.snapshot()
+    assert rep.drained
+    seen = _diff(before, after, "histograms", name, "count")
+    assert set(seen) == {f'{name}{{group="accel"}}',
+                         f'{name}{{group="cpu0"}}'}
+    assert sum(seen.values()) >= 2
+    assert after["histograms"][f'{name}{{group="accel"}}']["max"] <= 2 * one
+    engine.serve_jobs([Job(items=2)], batch_jobs=4, timeout_s=120.0)
+    last = tel.snapshot()
+    count = _diff(after, last, "histograms", name, "count")
+    total = _diff(after, last, "histograms", name, "sum")
+    assert sum(count.values()) >= 1
+    for key, n in count.items():
+        assert total[key] <= n * one, key
+
+
 def test_counters_match_what_the_serve_call_did(engine):
     tel = engine.telemetry
     before = tel.snapshot()

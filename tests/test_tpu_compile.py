@@ -103,14 +103,23 @@ def _unfused_ops(hlo: str):
     return fused, out
 
 
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b"])
+#: prompt and answer lengths of each model's cell: chat for the dense
+#: models, 2k documents for the hybrid
+CELL_LENGTHS = {"stablelm-1.6b": (128, 64), "yi-6b": (128, 64),
+                "granite-4.0-h-micro": (1984, 64)}
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b",
+                                  "granite-4.0-h-micro"])
 def test_decode_updates_cache_in_place_on_v5e(one_chip, arch):
-    """The engine's decode program (cache donated) on the chat cells'
-    shapes: the donated cache is written in place, and no layer's slice of
-    it is copied, as happens when the stored layout and the one the layer
-    reads and writes differ (head_dim 64 below the 128 lanes)."""
+    """The engine's decode program (cache donated) on the cells' shapes:
+    the donated cache is written in place, and no layer's slice of it is
+    copied, as happens when the stored layout and the one the layer
+    reads and writes differ (head_dim 64 below the 128 lanes). For the
+    hybrid the cache is keys and values beside each Mamba-2 layer's state
+    and conv window."""
     cfg = get_config(arch)
-    batch, max_len = 8, bucket(128 + 64)
+    batch, max_len = 8, bucket(sum(CELL_LENGTHS[arch]))
     params = _on(one_chip, jax.eval_shape(
         lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
     cache = _on(one_chip, M.init_cache(cfg, batch, max_len, abstract=True))
@@ -118,18 +127,36 @@ def test_decode_updates_cache_in_place_on_v5e(one_chip, arch):
     compiled = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t),
                        donate_argnums=(1,)).lower(params, cache,
                                                   tok).compile()
-    layer = batch * max_len * cfg.n_kv_heads * cfg.resolved_head_dim
+    stacked = {n: a for n, a in cache.items() if n != "pos"}
+    # the smallest slice of one layer of any stacked leaf
+    layer = min(a.size // a.shape[0] for a in stacked.values())
     fused, ops = _unfused_ops(compiled.as_text())
     # the parser read this text: fused computations and the fusions
     # that call them
     assert fused and any(o[1] == "fusion" for o in ops)
     big = [o for o in ops if o[1] == "copy" and o[2] >= layer]
     assert not big, big
-    kv_bytes = sum(cache[n].size * cache[n].dtype.itemsize
-                   for n in ("k", "v"))
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in stacked.values())
     ma = compiled.memory_analysis()
-    assert ma.alias_size_in_bytes >= kv_bytes
-    assert ma.temp_size_in_bytes < 0.05 * kv_bytes, ma.temp_size_in_bytes
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert ma.temp_size_in_bytes < 0.05 * cache_bytes, ma.temp_size_in_bytes
+
+
+def test_hybrid_prefill_of_the_largest_bucket_fits_v5e(one_chip):
+    """granite-4.0-h-micro's prefill of 16 rows of 2k documents, the
+    largest batch its cell warms up: the chunked SSD holds one chunk's
+    (256, 256) tiles at a time, so weights, temporaries and the cache fit
+    the chip."""
+    cfg = get_config("granite-4.0-h-micro")
+    prompt, answer = CELL_LENGTHS[cfg.arch_id]
+    params = _on(one_chip, jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    prompts = _on(one_chip, jax.ShapeDtypeStruct((16, prompt), jnp.int32))
+    compiled = jax.jit(lambda p, t: M.prefill(
+        cfg, p, t, None, max_len=bucket(prompt + answer))).lower(
+            params, prompts).compile()
+    used = _fits(compiled) + compiled.memory_analysis().output_size_in_bytes
+    assert used < 0.85 * V5E_HBM_BYTES, used
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
