@@ -8,6 +8,7 @@ trainer, server, dry-run and benchmarks are architecture-agnostic:
     logits, aux = forward(cfg, params, tokens, prefix_emb, remat=...)
     logits, cache = prefill(cfg, params, tokens, prefix_emb, max_len=...)
     logits, cache = decode_step(cfg, params, cache, tokens)
+    generated, cache = decode_loop(cfg, params, cache, tokens, steps)
     cache  = init_cache(cfg, batch, max_len, abstract=...)
     specs  = input_specs(cfg, shape)         # ShapeDtypeStruct stand-ins
 """
@@ -220,6 +221,25 @@ def decode_step(cfg: LMConfig, params, cache, tokens):
     if cfg.family == "hybrid":
         return hyb.decode_step(cfg, params, cache, tokens)
     raise ValueError(cfg.family)
+
+
+def decode_loop(cfg: LMConfig, params, cache, tokens, steps: int):
+    """``steps`` greedy decode steps in one program. tokens: (b, 1), the
+    token the first step reads. Returns (generated (b, steps) int32,
+    cache).
+
+    The cache rides in the loop's carry, as it rides in each step's layer
+    loop, so a donated cache is updated in place by every step.
+    """
+    def body(carry, _):
+        cache, tok = carry
+        logits, cache = decode_step(cfg, params, cache, tok)
+        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        return (cache, tok), tok[:, 0]
+
+    (cache, _), generated = jax.lax.scan(body, (cache, tokens), None,
+                                         length=steps)
+    return generated.T, cache
 
 
 def init_cache(cfg: LMConfig, batch: int, max_len: int, abstract=False):

@@ -149,7 +149,7 @@ class HeteroServeEngine:
         # a multi-device host); the default device uses self.params
         self._device_params: Dict[object, object] = {}
         self._device_params_lock = threading.Lock()
-        self._fns: Dict[int, tuple] = {}
+        self._fns: Dict[tuple, tuple] = {}
         # per executor key: what its chunks produced in the current serve
         # call (reset at the start of each; one writer per key)
         self._outputs: Dict[str, GroupOutputs] = {}
@@ -163,8 +163,11 @@ class HeteroServeEngine:
 
     # ------------------------------------------------------------------
     def _fns_for(self, b: int):
-        if b in self._fns:
-            return self._fns[b]
+        # keyed by the step count too, so that a program never decodes
+        # another count than the step that calls it announces
+        steps = self.decode_tokens - 1
+        if (b, steps) in self._fns:
+            return self._fns[b, steps]
         cfg = self.cfg
 
         @jax.jit
@@ -172,14 +175,14 @@ class HeteroServeEngine:
             return M.prefill(cfg, params, tokens, prefix,
                              max_len=self.max_len)
 
-        # the cache is donated: each call writes its new position in
-        # place, and the caller rebinds the cache it gets back
+        # a chunk's whole greedy decode, from the prefill's first token:
+        # one program, the cache donated and written in place at each step
         @partial(jax.jit, donate_argnums=(1,))
         def decode_fn(params, cache, tokens):
-            return M.decode_step(cfg, params, cache, tokens)
+            return M.decode_loop(cfg, params, cache, tokens, steps)
 
-        self._fns[b] = (prefill_fn, decode_fn)
-        return self._fns[b]
+        self._fns[b, steps] = (prefill_fn, decode_fn)
+        return self._fns[b, steps]
 
     def _prompt(self, idx: int, rng_salt: int = 0) -> np.ndarray:
         rng = np.random.Generator(np.random.PCG64(
@@ -269,22 +272,21 @@ class HeteroServeEngine:
                     .astype(jnp.int32)
             # the cache's size from its shapes: no wait for the device
             nbytes = sum(a.nbytes for a in jax.tree.leaves(cache))
-            toks = [tok]
-            for _ in range(self.decode_tokens - 1):
+            gen, steps = tok, self.decode_tokens - 1
+            if steps:
                 with telemetry_mod.annotate(tel, "exec.decode", group=key,
-                                            rows=b):
-                    logits, cache = decode_fn(params, cache, tok)
-                    tok = jnp.argmax(logits[:, -1], -1)[:, None] \
-                        .astype(jnp.int32)
-                toks.append(tok)
-            gen = jnp.concatenate(toks, axis=1)
+                                            rows=b, tokens=steps):
+                    rest, _ = decode_fn(params, cache, tok)
+                    gen = jnp.concatenate([tok, rest], axis=1)
             if tel is not None:
                 hold(gen, nbytes)
             return gen, batch["rows"]
 
         def fetch(outs):
             gen, rows = outs
-            tokens = np.asarray(gen)
+            # a copy: on the CPU a view would keep the outputs, and so the
+            # chunk's hold on its cache, alive for as long as the tokens
+            tokens = np.array(gen)
             rows = np.asarray(rows)
             if tel is not None:
                 real = int(np.count_nonzero(rows >= 0))

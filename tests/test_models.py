@@ -231,60 +231,93 @@ def test_ragged_decode_matches_forward(arch, kv_heads, unroll):
                                        rtol=2e-4, atol=2e-4)
 
 
-def test_engine_decode_donates_cache():
-    """The engine's decode program takes its cache by donation and gives
-    the tokens an undonated call of the same step gives."""
+def _served_and_stepped(cfg, prompt_len, decode_tokens):
+    """One call of the engine's decode program on a prefilled cache, and
+    an undonated step-by-step greedy decode of the same cache: (the
+    given cache, the program's tokens and cache, the steps' tokens and
+    cache)."""
     from repro.core.types import DeviceKind
     from repro.serve.engine import HeteroServeEngine
     from repro.train.trainer import GroupDef
 
-    cfg = get_reduced_config("stablelm-1.6b")
     eng = HeteroServeEngine(cfg, [GroupDef("accel", DeviceKind.ACCEL)],
-                            prompt_len=8, decode_tokens=4)
+                            prompt_len=prompt_len,
+                            decode_tokens=decode_tokens)
     prefill_fn, decode_fn = eng._fns_for(2)
     step = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
     tokens = np.stack([eng._prompt(i) for i in range(2)])
     logits, cache = prefill_fn(eng.params, tokens, None)
     tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
     kept = jax.tree.map(jnp.copy, cache)
-    want = tok
-    for _ in range(eng.decode_tokens - 1):
-        given = cache
-        logits, cache = decode_fn(eng.params, cache, tok)
-        assert given["k"].is_deleted() and given["v"].is_deleted()
+    got, got_cache = decode_fn(eng.params, cache, tok)
+    want = []
+    for _ in range(decode_tokens - 1):
+        logits, kept = step(eng.params, kept, tok)
         tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-        ref_logits, kept = step(eng.params, kept, want)
-        want = jnp.argmax(ref_logits[:, -1], -1)[:, None].astype(jnp.int32)
-        np.testing.assert_array_equal(np.asarray(tok), np.asarray(want))
-    np.testing.assert_array_equal(np.asarray(cache["k"]),
-                                  np.asarray(kept["k"]))
+        want.append(tok)
+    return (cache, np.asarray(got), got_cache,
+            np.asarray(jnp.concatenate(want, axis=1)), kept)
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+def test_engine_decode_donates_cache(unroll):
+    """The engine's decode program runs a chunk's whole greedy decode in
+    one call that takes its cache by donation, and gives the tokens and
+    the cache an undonated step-by-step decode gives (layer loop scanned
+    or unrolled)."""
+    cfg = get_reduced_config("stablelm-1.6b").replace(decode_unroll=unroll)
+    given, got, cache, want, kept = _served_and_stepped(cfg, 8, 4)
+    assert all(a.is_deleted() for a in jax.tree.leaves(given))
+    assert got.shape == (2, 3) and got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    for name in ("k", "v", "pos"):
+        np.testing.assert_array_equal(np.asarray(cache[name]),
+                                      np.asarray(kept[name]))
 
 
 def test_engine_decode_donates_hybrid_cache():
     """The hybrid layer pattern's decode program takes its whole cache
     (SSM state, conv windows, keys and values) by donation, and serves
-    the tokens an undonated call of the same step serves."""
-    from repro.core.types import DeviceKind
-    from repro.serve.engine import HeteroServeEngine
-    from repro.train.trainer import GroupDef
+    the tokens and leaves the cache an undonated step-by-step decode
+    serves and leaves."""
     cfg = get_reduced_config("granite-4.0-h-micro")
-    eng = HeteroServeEngine(cfg, [GroupDef("accel", DeviceKind.ACCEL)],
-                            prompt_len=11, decode_tokens=4)
-    prefill_fn, decode_fn = eng._fns_for(2)
-    step = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
-    tokens = np.stack([eng._prompt(i) for i in range(2)])
-    logits, cache = prefill_fn(eng.params, tokens, None)
-    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-    kept = jax.tree.map(jnp.copy, cache)
-    for _ in range(eng.decode_tokens - 1):
-        given = cache
-        logits, cache = decode_fn(eng.params, cache, tok)
-        assert all(a.is_deleted() for a in jax.tree.leaves(given))
-        _, kept = step(eng.params, kept, tok)
-        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+    given, got, cache, want, kept = _served_and_stepped(cfg, 11, 4)
+    assert all(a.is_deleted() for a in jax.tree.leaves(given))
+    assert got.shape == (2, 3) and got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
     for name in ("ssm_state", "conv", "k", "v", "pos"):
         np.testing.assert_array_equal(np.asarray(cache[name]),
                                       np.asarray(kept[name]))
+
+
+def test_engine_serves_the_first_token_alone_without_a_decode_program(
+        monkeypatch):
+    """With one token to generate, a chunk is its prefill and the argmax
+    of its logits: no decode program is built or called."""
+    from repro.core.types import DeviceKind
+    from repro.queue import Job
+    from repro.serve.engine import HeteroServeEngine
+    from repro.train.trainer import GroupDef
+
+    def no_decode(*args):
+        raise AssertionError("a decode program was built")
+
+    monkeypatch.setattr(M, "decode_loop", no_decode)
+    cfg = get_reduced_config("stablelm-1.6b")
+    eng = HeteroServeEngine(cfg, [GroupDef("accel", DeviceKind.ACCEL)],
+                            prompt_len=8, decode_tokens=1)
+    rep = eng.serve_jobs([Job(items=2) for _ in range(2)], batch_jobs=2,
+                         timeout_s=120.0)
+    assert rep.drained and rep.done == 2
+    sample = np.asarray(rep.outputs["accel"]["sample"]["tokens"])
+    rows = rep.outputs["accel"]["sample"]["rows"]
+    assert sample.shape[1] == 1
+    prompts = np.zeros((sample.shape[0], 8), np.int32)
+    prompts[:len(rows)] = np.stack([eng._prompt(i) for i in rows])
+    prefill_fn, _ = eng._fns_for(sample.shape[0])
+    logits, _ = prefill_fn(eng.params, prompts, None)
+    np.testing.assert_array_equal(
+        sample[:len(rows), 0], np.argmax(logits[:len(rows), -1], -1))
 
 
 @pytest.mark.parametrize("arch", ["zamba2-1.2b", "granite-4.0-h-micro"])

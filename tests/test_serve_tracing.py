@@ -156,6 +156,7 @@ def test_each_layer_scopes_its_work_with_ids_and_parents(engine):
 
 
 def _profiled_events(logdir):
+    """``repro.*`` event name -> [(plane name, the event's ids)]."""
     import jax
     path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                      recursive=True)[0]
@@ -165,14 +166,16 @@ def _profiled_events(logdir):
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith("repro."):
-                    out.setdefault(ev.name, []).append(plane.name)
+                    out.setdefault(ev.name, []).append(
+                        (plane.name, dict(ev.stats)))
     return out
 
 
 def test_profiler_sees_each_decode_call_and_chunk_phase(engine, tmp_path):
     """Under a profiler session each layer's work lands on the host plane
-    as ``repro.*`` events: one ``exec.decode`` per decode call, which is
-    ``decode_tokens - 1`` per chunk, and one h2d/fetch per chunk."""
+    as ``repro.*`` events: one ``exec.decode`` per chunk, the one call
+    that decodes its ``decode_tokens - 1`` tokens, and one h2d/fetch per
+    chunk."""
     import jax
     tel = engine.telemetry
     jobs = [Job(items=2) for _ in range(6)]
@@ -188,8 +191,9 @@ def test_profiler_sees_each_decode_call_and_chunk_phase(engine, tmp_path):
     chunks = sum(_diff(before, after, "counters", "sched.chunks").values())
     assert chunks > 0
     found = _profiled_events(str(tmp_path))
-    assert len(found["repro.exec.decode"]) \
-        == chunks * (DECODE_TOKENS - 1)
+    assert len(found["repro.exec.decode"]) == chunks
+    assert all(ids["tokens"] == DECODE_TOKENS - 1
+               for _, ids in found["repro.exec.decode"])
     for name in ("repro.exec.inputs", "repro.exec.h2d",
                  "repro.exec.prefill", "repro.exec.fetch"):
         assert len(found[name]) == chunks, name
@@ -197,7 +201,8 @@ def test_profiler_sees_each_decode_call_and_chunk_phase(engine, tmp_path):
     for name in ("repro.serve.build", "repro.serve.drain", "repro.svc.pop",
                  "repro.svc.complete", "repro.sched.await"):
         assert found.get(name), name
-    assert all(p.startswith("/host:") for ps in found.values() for p in ps)
+    assert all(p.startswith("/host:") for evs in found.values()
+               for p, _ in evs)
 
 
 def test_federated_serve_span_brackets_its_runtimes_epochs(engine):

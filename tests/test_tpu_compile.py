@@ -85,9 +85,14 @@ def test_stablelm_serve_step_compiles_for_v5e(one_chip, batch):
     _fits(jax.jit(decode).lower(params, cache, tok).compile())
 
 
+#: bytes per element of the HLO element types these programs hold
+HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+             "u32": 4, "f32": 4}
+
+
 def _unfused_ops(hlo: str):
     """The fused computations' names, and (computation, opcode, element
-    count) of each array-valued op that no fusion holds."""
+    count, bytes, shape) of each array-valued op that no fusion holds."""
     fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
     comp, out = None, []
     for line in hlo.splitlines():
@@ -95,11 +100,13 @@ def _unfused_ops(hlo: str):
         if head:
             comp = head.group(1)
             continue
-        op = re.match(r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
-                      line)
+        op = re.match(r"\s+(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(", line)
         if op and comp not in fused:
-            dims = [int(d) for d in op.group(1).split(",") if d]
-            out.append((comp, op.group(2), math.prod(dims)))
+            shape = tuple(int(d) for d in op.group(2).split(",") if d)
+            n = math.prod(shape)
+            out.append((comp, op.group(3), n,
+                        n * HLO_BYTES.get(op.group(1), 4), shape))
     return fused, out
 
 
@@ -109,37 +116,99 @@ CELL_LENGTHS = {"stablelm-1.6b": (128, 64), "yi-6b": (128, 64),
                 "granite-4.0-h-micro": (1984, 64)}
 
 
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b",
-                                  "granite-4.0-h-micro"])
-def test_decode_updates_cache_in_place_on_v5e(one_chip, arch):
-    """The engine's decode program (cache donated) on the cells' shapes:
-    the donated cache is written in place, and no layer's slice of it is
-    copied, as happens when the stored layout and the one the layer
-    reads and writes differ (head_dim 64 below the 128 lanes). For the
-    hybrid the cache is keys and values beside each Mamba-2 layer's state
-    and conv window."""
+def _cell_decode(one_chip, arch):
+    """The model, its params, an 8-row cache of the cell's bucket and the
+    token a decode reads, as v5e arguments."""
     cfg = get_config(arch)
     batch, max_len = 8, bucket(sum(CELL_LENGTHS[arch]))
     params = _on(one_chip, jax.eval_shape(
         lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
     cache = _on(one_chip, M.init_cache(cfg, batch, max_len, abstract=True))
     tok = _on(one_chip, jax.ShapeDtypeStruct((batch, 1), jnp.int32))
-    compiled = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t),
-                       donate_argnums=(1,)).lower(params, cache,
-                                                  tok).compile()
+    return cfg, params, cache, tok
+
+
+#: the most a decode loop may copy of its cache as the program starts or
+#: ends, as a share of the cache's bytes. granite's loop carries its conv
+#: windows in another layout than the argument's and copies them in and
+#: out (2 x 7.5 MB, 2 % of its cache); a copy of any key, value or state
+#: leaf is 9 % of a cache or more.
+ENTRY_CACHE_COPY_SHARE = 0.025
+
+
+def _assert_in_place(compiled, params, cache, loop: bool = False) -> None:
+    """The donated cache is written in place: no copy of one layer's
+    slice of any stacked leaf or more, the whole cache aliased to the
+    output, and temporaries a small share of it.
+
+    ``loop``: the program is a loop of decode steps, which must stay one
+    while loop at the program's entry, its body checked. The compiler may carry a weight
+    stack through it in the layout the products read and relay the stack
+    once as the program starts: a copy at the program's entry shaped as a
+    params leaf is allowed, and the temporaries may hold it. Copies of
+    cache leaves there may hold ENTRY_CACHE_COPY_SHARE of the cache's
+    bytes in all; no other copy of a layer's slice may run."""
     stacked = {n: a for n, a in cache.items() if n != "pos"}
     # the smallest slice of one layer of any stacked leaf
     layer = min(a.size // a.shape[0] for a in stacked.values())
-    fused, ops = _unfused_ops(compiled.as_text())
+    hlo = compiled.as_text()
+    entry = re.search(r"^ENTRY %([\w.\-]+) ", hlo, re.M)
+    fused, ops = _unfused_ops(hlo)
     # the parser read this text: fused computations and the fusions
     # that call them
     assert fused and any(o[1] == "fusion" for o in ops)
-    big = [o for o in ops if o[1] == "copy" and o[2] >= layer]
+    at_entry = [o for o in ops if loop and o[0] == entry.group(1)
+                and o[1] == "copy"]
+    weights = {a.shape for a in jax.tree.leaves(params)}
+    relaid = [o for o in at_entry if o[4] in weights]
+    leaves = {a.shape for a in stacked.values()}
+    cache_copies = [o for o in at_entry if o[4] in leaves]
+    big = [o for o in ops if o[1] == "copy" and o[2] >= layer
+           and o not in relaid + cache_copies]
     assert not big, big
     cache_bytes = sum(a.size * a.dtype.itemsize for a in stacked.values())
+    copied = sum(o[3] for o in cache_copies)
+    assert copied <= ENTRY_CACHE_COPY_SHARE * cache_bytes, cache_copies
+    if loop:
+        # one loop of steps at the entry, its body among the checked
+        bodies = re.findall(r" while\(.*?body=%([\w.\-]+)",
+                            hlo[entry.start():])
+        assert len(bodies) == 1 and any(o[0] == bodies[0] for o in ops)
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= cache_bytes
-    assert ma.temp_size_in_bytes < 0.05 * cache_bytes, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes < 0.05 * cache_bytes + copied + sum(
+        o[3] for o in relaid), ma.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b",
+                                  "granite-4.0-h-micro"])
+def test_decode_updates_cache_in_place_on_v5e(one_chip, arch):
+    """One decode step (cache donated) on the cells' shapes: the donated
+    cache is written in place, and no layer's slice of it is copied, as
+    happens when the stored layout and the one the layer reads and writes
+    differ (head_dim 64 below the 128 lanes). For the hybrid the cache is
+    keys and values beside each Mamba-2 layer's state and conv window."""
+    cfg, params, cache, tok = _cell_decode(one_chip, arch)
+    compiled = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  tok).compile()
+    _assert_in_place(compiled, params, cache)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "yi-6b",
+                                  "granite-4.0-h-micro"])
+def test_decode_loop_updates_cache_in_place_on_v5e(one_chip, arch):
+    """The engine's decode program, a chunk's 63 greedy steps in one loop
+    with the cache donated, on the cells' shapes: the cache rides in the
+    loop's carry and each step writes it in place; no step copies the
+    carried cache or a layer's slice of it, and the program copies at
+    most granite's conv windows of its cache once per call."""
+    cfg, params, cache, tok = _cell_decode(one_chip, arch)
+    steps = CELL_LENGTHS[arch][1] - 1
+    compiled = jax.jit(lambda p, c, t: M.decode_loop(cfg, p, c, t, steps),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  tok).compile()
+    _assert_in_place(compiled, params, cache, loop=True)
 
 
 def test_hybrid_prefill_of_the_largest_bucket_fits_v5e(one_chip):
